@@ -17,14 +17,27 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+# The host phases of TpuUniverse.apply_changes before its launch.
+HOST_SPANS = ("ingest.prepare", "ingest.stage", "ingest.window_census", "ingest.put")
+
+
+def host_seconds(snapshot: dict) -> float:
+    hists = snapshot["histograms"]
+    return sum(hists.get(f"span.{name}.seconds", {}).get("sum", 0.0) for name in HOST_SPANS)
+
+
 def main() -> None:
     replicas = int(os.environ.get("FLEET_REPLICAS", "256"))
     rounds = int(os.environ.get("FLEET_ROUNDS", "3"))
     from peritext_tpu import compile_cache
     from peritext_tpu.bench.workloads import make_merge_workload
     from peritext_tpu.ops import TpuUniverse
+    from peritext_tpu.runtime import telemetry
 
     compile_cache.enable()
+    # The host phases before each launch are telemetry spans; the demo
+    # reads their histograms for its host/device split.
+    telemetry.enable()
     # Four distinct writer streams over a shared 400-char genesis document.
     workload = make_merge_workload(doc_len=400, ops_per_merge=48, num_streams=4, seed=7)
     streams = workload["streams"]
@@ -51,8 +64,8 @@ def main() -> None:
     total_ops = 0
     wall = 0.0
     # The host/device split reports the measured rounds only, so exclude
-    # the genesis bootstrap's host share accumulated above.
-    host_before_rounds = uni.stats["host_seconds"]
+    # the genesis bootstrap's host share recorded above.
+    host_before_rounds = host_seconds(telemetry.snapshot())
     for rnd in range(rounds):
         # Each replica merges one writer stream per round, round-robin — so
         # after every round, replicas on the same stream schedule must agree.
@@ -87,9 +100,9 @@ def main() -> None:
     spans = uni.spans(names[0])
     text = "".join(s["text"] for s in spans)
     marked = sum(1 for s in spans if s["marks"])
-    host_s = uni.stats["host_seconds"] - host_before_rounds
-    # Device share = barriered round wall time minus the host control plane
-    # (dispatch_seconds alone would miss async execution).
+    host_s = host_seconds(telemetry.snapshot()) - host_before_rounds
+    # Device share = barriered round wall time minus the host phases
+    # before each launch.
     dev_s = max(wall - host_s, 0.0)
     print(
         f"\nfleet consistent: {replicas} replicas, {total_ops} ops merged; "

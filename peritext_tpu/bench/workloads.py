@@ -961,210 +961,6 @@ def time_streaming_ab(
     }
 
 
-def time_telemetry_overhead_ab(
-    num_replicas: int = 256,
-    doc_len: int = 1000,
-    ops_per_merge: int = 64,
-    rounds: int = 4,
-    seed: int = 0,
-    best_of: int = 3,
-) -> Dict[str, Any]:
-    """A/B the telemetry plane's overhead on the patched-fleet steady
-    state (the BASELINE config-6 shape) IN THE SAME PROCESS.
-
-    Authors the round streams once, compiles every shape on a throwaway
-    universe, then alternates telemetry-OFF / telemetry-ON legs
-    ``best_of`` times — a fresh universe per leg, identical streams —
-    scoring each leg by its WARM rounds (2..N, the steady state the
-    winner cache exists for) and keeping the fastest leg per arm, so
-    background load hits both arms symmetrically.  The ON leg runs the
-    FULL observability stack — registry collection, a live tracer
-    (JSONL to a scratch file), the flight recorder, one causal flow lane
-    per fleet round (the queue-flush shape: mint, step through the ingest
-    seams, terminate with an e2e observation), live SLO evaluators fed
-    from that e2e observation and the launch counters, tail-sampled lane
-    buffering (head p=0.25, a slow rule that never fires — every lane
-    pays buffer + verdict), and a running status flusher writing the ops
-    surface to a scratch path every 250ms — so the measured overhead
-    covers the whole ISSUE 13 stack, not just counters; the telemetry
-    contract (CLAUDE.md "Observability") is that ON stays within 2% of
-    OFF here.
-    """
-    import tempfile
-
-    from peritext_tpu.ops import TpuUniverse
-    from peritext_tpu.runtime import slo as slo_plane
-    from peritext_tpu.runtime import telemetry
-    from peritext_tpu.testing import patch_path_env
-
-    rng = random.Random(seed)
-    writers, _, genesis = _patched_writers(doc_len, rng)
-    made = [
-        _make_patched_stream(rng, writers, ops_per_merge, min_marks=2)
-        for _ in range(rounds)
-    ]
-    streams = [s for s, _ in made]
-    ops_per_round = [n for _, n in made]
-    names = [f"r{i}" for i in range(num_replicas)]
-    capacity = 1
-    while capacity < doc_len + sum(ops_per_round) + 64:
-        capacity *= 2
-
-    def build():
-        uni = TpuUniverse(names, capacity=capacity)
-        uni.apply_changes_with_patches({n: [genesis] for n in names})
-        return uni
-
-    def leg(traced: bool = False) -> float:
-        uni = build()
-        per_round = []
-        for stream in streams:
-            start = time.perf_counter()
-            if traced:
-                # One causal lane per round — the config-6 steady state's
-                # flush granularity — so the ON arm pays the real flow
-                # cost: mint, s/t/f emission at every ingest seam, the
-                # terminal e2e observation, and a recorder event.
-                ctx = telemetry.flow("bench.round", changes=len(stream))
-                with telemetry.span("bench.flush", depth=len(stream)):
-                    telemetry.flow_point(ctx)
-                    with telemetry.flowing((ctx,)):
-                        uni.apply_changes_with_patches(
-                            {n: list(stream) for n in names}
-                        )
-                    if ctx is not None:
-                        telemetry.observe(
-                            "e2e.enqueue_to_applied",
-                            telemetry.flow_elapsed_s(ctx),
-                        )
-                        telemetry.flow_point(ctx, terminal=True)
-                telemetry.record("bench.round", flow=ctx, outcome="applied")
-            else:
-                uni.apply_changes_with_patches({n: list(stream) for n in names})
-            per_round.append(time.perf_counter() - start)
-        return sum(per_round[1:])  # warm rounds only
-
-    was_enabled = telemetry.enabled
-    off_s: List[float] = []
-    on_s: List[float] = []
-    # Detach any live tracer (e.g. an ambient PERITEXT_TRACE) for the
-    # measurement: the ON arm traces into its own scratch file (so the
-    # serialization + file I/O cost IS measured, on a file this run owns),
-    # and the OFF arm must not silently blank the caller's trace.
-    # Restored (not closed) afterwards.
-    saved_tracer, telemetry._tracer = telemetry._tracer, None
-    # The whole new-stack state this harness toggles is saved and restored
-    # (the internal-attr juggling the tracer detach already does): an
-    # ambient PERITEXT_TRACE_SAMPLE / PERITEXT_STATUS configuration must
-    # survive the measurement.
-    saved_sampling = (
-        telemetry._sample_p,
-        telemetry._sample_seed,
-        telemetry._tail_slow_us,
-        telemetry._tail_error,
-        telemetry._tail_breach,
-    )
-    saved_status_path = telemetry._status_path
-    ambient = telemetry._flusher
-    saved_flusher_interval = None if ambient is None else ambient.interval
-    scratch = tempfile.NamedTemporaryFile(
-        suffix=".trace.jsonl", prefix="peritext-overhead-", delete=False
-    )
-    scratch.close()
-    status_scratch = tempfile.NamedTemporaryFile(
-        suffix=".status.json", prefix="peritext-overhead-", delete=False
-    )
-    status_scratch.close()
-    try:
-        with patch_path_env(None), slo_plane.guarded(
-            # Live evaluators on both feed kinds: the per-round e2e
-            # observation and the per-launch attempt/failure counters.
-            "e2e.enqueue_to_applied:p95=50,window=64;"
-            "ingest.launch:err_rate=0.5,window=64"
-        ):
-            throwaway = build()
-            for stream in streams:
-                throwaway.apply_changes_with_patches(
-                    {n: list(stream) for n in names}
-                )
-            for _ in range(best_of):
-                telemetry.disable()
-                # disable() deliberately keeps the tracer; this harness
-                # must CLOSE its own scratch tracer before detaching it
-                # (re-enable below reopens the path truncating) or each
-                # iteration leaks the fd + buffered events.
-                own = telemetry._tracer
-                if own is not None:
-                    own.close()
-                telemetry._tracer = None
-                # The flusher keeps ticking across disable(); park it so
-                # the OFF arm is a true telemetry-off baseline.
-                flusher = telemetry._flusher
-                if flusher is not None:
-                    flusher.stop_event.set()
-                    telemetry._flusher = None
-                off_s.append(leg())
-                telemetry.enable(
-                    trace=scratch.name,
-                    status_path=status_scratch.name,
-                    metrics_interval=0.25,
-                )
-                # Head-sample at 0.25 with a tail rule that never fires
-                # (60s slow bar): every lane pays the buffered path's
-                # full cost — buffer, verdict, (sometimes) replay.
-                telemetry.set_trace_sampling(sample=0.25, tail="slow:60000|error")
-                on_s.append(leg(traced=True))
-    finally:
-        # The warmup lives inside the try too: a compile failure must not
-        # leak the detached-tracer state into the rest of the process.
-        own = telemetry._tracer
-        if own is not None:
-            own.close()
-        telemetry._tracer = saved_tracer
-        flusher = telemetry._flusher
-        if flusher is not None:
-            flusher.stop_event.set()
-            telemetry._flusher = None
-        (
-            telemetry._sample_p,
-            telemetry._sample_seed,
-            telemetry._tail_slow_us,
-            telemetry._tail_error,
-            telemetry._tail_breach,
-        ) = saved_sampling
-        telemetry._status_path = saved_status_path
-        with telemetry._flow_lock:
-            telemetry._lane_buf.clear()
-        if was_enabled:
-            # Passing the saved interval recreates the ambient periodic
-            # flusher the measurement parked (the OFF arm stops whatever
-            # flusher is running) — the salvage contract must survive
-            # this harness.
-            telemetry.enable(metrics_interval=saved_flusher_interval)
-        else:
-            telemetry.disable()
-        for path in (scratch.name, status_scratch.name):
-            try:
-                os.unlink(path)
-            except OSError:
-                pass
-    warm_ops = num_replicas * sum(ops_per_round[1:])
-    off, on = min(off_s), min(on_s)
-    return {
-        "replicas": num_replicas,
-        "doc_len": doc_len,
-        "rounds": rounds,
-        "best_of": best_of,
-        "ops_per_round": ops_per_round,
-        "on_arm": "registry+tracer+recorder+flow+slo+tail_sampling+status",
-        "telemetry_off_warm_s": off,
-        "telemetry_on_warm_s": on,
-        "telemetry_off_warm_ops_per_sec": warm_ops / off,
-        "telemetry_on_warm_ops_per_sec": warm_ops / on,
-        "on_vs_off_overhead": on / off - 1.0,
-    }
-
-
 # -- serving-plane A/B (naive per-change ingest vs continuous batching) -------
 
 

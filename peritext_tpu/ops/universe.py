@@ -828,15 +828,6 @@ class TpuUniverse:
             "launch_retries": 0,
             "degraded_batches": 0,
             "fastfails": 0,
-            # Wall-clock split of apply_changes: host control plane
-            # (gate/encode/fuse/pad/commit) vs launch *dispatch*.  JAX
-            # dispatch is async — device execution lands on whichever later
-            # readback blocks — so dispatch_seconds is NOT device time;
-            # measure device cost with an explicit readback barrier (the
-            # fleet demo does).  At fleet scale the host share must stay
-            # below the measured device share (BASELINE configs 4-5).
-            "host_seconds": 0.0,
-            "dispatch_seconds": 0.0,
         }
 
     # -- fleet elasticity ---------------------------------------------------
@@ -1191,6 +1182,14 @@ class TpuUniverse:
         return ordered, dupes
 
     def _prepare(self, batches: List[Sequence[Change]]) -> Dict[str, Any]:
+        """Spanned wrapper for :meth:`_prepare_impl`: the first of the
+        host phases before a launch (``ingest.prepare``; then
+        ``ingest.stage``, ``ingest.window_census`` and ``ingest.put``,
+        siblings on the profiler's host plane)."""
+        with telemetry.span("ingest.prepare"):
+            return self._prepare_impl(batches)
+
+    def _prepare_impl(self, batches: List[Sequence[Change]]) -> Dict[str, Any]:
         """Gate + encode every replica without touching committed state.
 
         Raises before any commit if any replica's batch is causally
@@ -1548,19 +1547,16 @@ class TpuUniverse:
                     f"w_cap={wplan['w_cap']}; rows={rows}"
                 )
 
-    def _window_fallback(
-        self, launches: int = 1, d2h_bytes: int = 0, elapsed: float = 0.0
-    ) -> None:
+    def _window_fallback(self, launches: int = 1, d2h_bytes: int = 0) -> None:
         """Tally a windowed launch the device census check rejected (stale
         mirror / census bug): the caller discards the result and relaunches
         the full-table path — correctness never depends on the census.  The
         rejected launch still ran to completion on device, so it stays in
-        the launch/traffic/latency accounting (its window readback is real
+        the launch/traffic accounting (its window readback is real
         D2H traffic; the op-tensor H2D upload is shared with the relaunch
         and tallied once, at the relaunch's commit)."""
         self.stats["window_fallbacks"] = self.stats.get("window_fallbacks", 0) + 1
         self.stats["launches"] += launches
-        self.stats["dispatch_seconds"] += elapsed
         _log.warning(
             "windowed merge census check failed on device; relaunching the "
             "full-table path"
@@ -1852,58 +1848,53 @@ class TpuUniverse:
         scan step per op.  Set PERITEXT_MERGE_PATH=scan to force the
         sequential two-phase scan path (debugging/differential runs).
         """
-        t_host = time.perf_counter()
         batches = self._normalize_batches(per_replica)
         prep = self._prepare(batches)
         groups, group_of = prep["groups"], prep["group_of"]
         use_scan = os.environ.get("PERITEXT_MERGE_PATH") == "scan"
-
-        # Split once per distinct group; replicas sharing a stream share it.
-        any_rows = False
-        text_rows_list: List[np.ndarray] = []
-        mark_rows_list: List[np.ndarray] = []
-        max_mark = 0
-        for g in groups:
-            any_rows = any_rows or g["rows"].shape[0] > 0
-            text_rows, mark_rows = split_rows(g["rows"])
-            text_rows_list.append(text_rows)
-            mark_rows_list.append(mark_rows)
-            max_mark = max(max_mark, mark_rows.shape[0])
         group_sizes, _ = self._account_rows(groups, group_of)
 
         self._ensure_capacity(prep["need_len"], prep["need_marks"])
-        if not any_rows:
+        if not any(g["rows"].shape[0] for g in groups):
             self._commit(prep)
             return
-        # Cost model: a placement round does O(L) x the vector work of one
-        # scan step, so sorted wins only when the batch's reference depth D
-        # is far below its row count (concurrent merge batches: D is 1-3).
-        # Deep single-writer histories (replaying one actor's whole log,
-        # where most inserts reference same-batch elements) degenerate to
-        # D ~ L; prepare_sorted_batch re-fuses those for the sequential
-        # scan before any padding happens.
-        sorted_prep = prepare_sorted_batch(
-            text_rows_list,
-            max_run=K.MAX_RUN_LEN if use_scan else 0,
-            fallback_max_rounds=None
-            if use_scan
-            else int(os.environ.get("PERITEXT_SORTED_MAX_ROUNDS", "8")),
-        )
-        if sorted_prep["fell_back"]:
-            use_scan = True
-            self.stats["scan_fallbacks"] += 1
-        mark_pad = bucket_length(max(max_mark, 1))
-        g_mark = np.stack([pad_rows(rows, mark_pad) for rows in mark_rows_list])
-        # One vectorized gather expands groups to the replica batch.
-        text_ops = sorted_prep["text"][group_of]
-        mark_ops = g_mark[group_of]
-        bufs = sorted_prep["bufs"][group_of]
-        rounds = sorted_prep["rounds"][group_of]
-        ranks = self._ranks_jax()
-        pad_per_group = (sorted_prep["text"][:, :, K.K_KIND] == K.KIND_PAD).sum(axis=1) + (
-            g_mark[:, :, K.K_KIND] == K.KIND_PAD
-        ).sum(axis=1)
-        self.stats["rows_padded"] += int((pad_per_group * group_sizes).sum())
+        with telemetry.span("ingest.stage"):
+            # Split once per distinct group; replicas sharing a stream
+            # share it.
+            text_rows_list: List[np.ndarray] = []
+            mark_rows_list: List[np.ndarray] = []
+            for g in groups:
+                text_rows, mark_rows = split_rows(g["rows"])
+                text_rows_list.append(text_rows)
+                mark_rows_list.append(mark_rows)
+            # Cost model: a placement round does O(L) x the vector work of
+            # one scan step, so sorted wins only when the batch's reference
+            # depth D is far below its row count (concurrent merge batches:
+            # D is 1-3).  Deep single-writer histories (replaying one
+            # actor's whole log, where most inserts reference same-batch
+            # elements) degenerate to D ~ L; prepare_sorted_batch re-fuses
+            # those for the sequential scan before any padding happens.
+            sorted_prep = prepare_sorted_batch(
+                text_rows_list,
+                max_run=K.MAX_RUN_LEN if use_scan else 0,
+                fallback_max_rounds=None
+                if use_scan
+                else int(os.environ.get("PERITEXT_SORTED_MAX_ROUNDS", "8")),
+            )
+            if sorted_prep["fell_back"]:
+                use_scan = True
+                self.stats["scan_fallbacks"] += 1
+            mark_pad = bucket_length(max(max(m.shape[0] for m in mark_rows_list), 1))
+            g_mark = np.stack([pad_rows(rows, mark_pad) for rows in mark_rows_list])
+            # One vectorized gather expands groups to the replica batch.
+            text_ops = sorted_prep["text"][group_of]
+            mark_ops = g_mark[group_of]
+            bufs = sorted_prep["bufs"][group_of]
+            rounds = sorted_prep["rounds"][group_of]
+            pad_per_group = (
+                sorted_prep["text"][:, :, K.K_KIND] == K.KIND_PAD
+            ).sum(axis=1) + (g_mark[:, :, K.K_KIND] == K.KIND_PAD).sum(axis=1)
+            self.stats["rows_padded"] += int((pad_per_group * group_sizes).sum())
         # Frontier-bounded window merge (ISSUE 12): when the host census can
         # bound every op's reach, gather the window, merge O(window), and
         # scatter back — the full-table path stays the adaptive fallback.
@@ -1911,16 +1902,18 @@ class TpuUniverse:
         # ONE batched host->device transfer for the launch's op tensors
         # (per-array device_put overhead dominates small windowed
         # launches); retries and the census-rejection relaunch reuse them.
-        if wplan is not None:
-            d_text, d_rounds, d_mark, d_bufs, d_wstart, d_whull = self._put(
-                (text_ops, rounds, mark_ops, bufs, wplan["starts"], wplan["hulls"])
-            )
-        else:
-            d_text, d_rounds, d_mark, d_bufs = self._put(
-                (text_ops, rounds, mark_ops, bufs)
-            )
-        t_dev = time.perf_counter()
-        self.stats["host_seconds"] += t_dev - t_host
+        # device_put may return before the copy lands: the span can end at
+        # the enqueue.
+        with telemetry.span("ingest.put"):
+            ranks = self._ranks_jax()
+            if wplan is not None:
+                d_text, d_rounds, d_mark, d_bufs, d_wstart, d_whull = self._put(
+                    (text_ops, rounds, mark_ops, bufs, wplan["starts"], wplan["hulls"])
+                )
+            else:
+                d_text, d_rounds, d_mark, d_bufs = self._put(
+                    (text_ops, rounds, mark_ops, bufs)
+                )
 
         strict = os.environ.get("PERITEXT_STRICT_COMMIT") == "1"
         if wplan is not None:
@@ -1953,7 +1946,6 @@ class TpuUniverse:
                 if not _degrade_enabled():
                     raise
                 self._degrade_apply(prep)
-                self.stats["dispatch_seconds"] += time.perf_counter() - t_dev
                 return
             if bool(wrec_np["wok"].all()):
                 self.states = new_states
@@ -1961,7 +1953,6 @@ class TpuUniverse:
                 self.stats["windowed_launches"] = (
                     self.stats.get("windowed_launches", 0) + 1
                 )
-                self.stats["dispatch_seconds"] += time.perf_counter() - t_dev
                 if telemetry.enabled:
                     telemetry.flow_steps(path="windowed", window=int(wplan["w_cap"]))
                     telemetry.counter("ingest.launches")
@@ -1980,21 +1971,14 @@ class TpuUniverse:
                         "ingest.d2h_bytes",
                         int(sum(v.nbytes for v in wrec_np.values())),
                     )
-                    telemetry.observe(
-                        "ingest.dispatch_seconds", time.perf_counter() - t_dev
-                    )
                 self._wcaches = None
                 self._mirror_commit(wplan, wrec_np, prep)
-                t_host = time.perf_counter()
                 self._commit(prep)
-                self.stats["host_seconds"] += time.perf_counter() - t_host
                 return
             # Device census check rejected the window: relaunch full-table.
             self._window_fallback(
-                d2h_bytes=int(sum(v.nbytes for v in wrec_np.values())),
-                elapsed=time.perf_counter() - t_dev,
+                d2h_bytes=int(sum(v.nbytes for v in wrec_np.values()))
             )
-            t_dev = time.perf_counter()
 
         def attempt():
             faults.fire("device_launch")
@@ -2030,14 +2014,12 @@ class TpuUniverse:
             if not _degrade_enabled():
                 raise  # committed state untouched: nothing was assigned
             self._degrade_apply(prep)
-            self.stats["dispatch_seconds"] += time.perf_counter() - t_dev
             return
         self.states = new_states
         # "launches" counts SUCCESSFUL kernel launches on every ingest path
         # (failed attempts show up in launch_retries; degraded batches in
         # degraded_batches), so launch/batch ratios are path-independent.
         self.stats["launches"] += 1
-        self.stats["dispatch_seconds"] += time.perf_counter() - t_dev
         if telemetry.enabled:
             telemetry.counter("ingest.launches")
             telemetry.counter(
@@ -2052,15 +2034,10 @@ class TpuUniverse:
                     + rounds.nbytes
                 ),
             )
-            telemetry.observe(
-                "ingest.dispatch_seconds", time.perf_counter() - t_dev
-            )
         # Non-patched merges rewrite boundary rows without maintaining the
         # patched path's winner cache.
         self._wcaches = None
-        t_host = time.perf_counter()
         self._commit(prep)
-        self.stats["host_seconds"] += time.perf_counter() - t_host
 
     # -- patch-emitting ingestion (the incremental codepath) ----------------
 
@@ -2196,28 +2173,31 @@ class TpuUniverse:
         )
         sorted_prep = None
         if not use_scan:
-            text_rows_list: List[np.ndarray] = []
-            mark_rows_list: List[np.ndarray] = []
-            text_pos_list: List[np.ndarray] = []
-            mark_pos_list: List[np.ndarray] = []
-            for g in groups:
-                rows = g["rows"]
-                rp = np.asarray(g["row_pos"])
-                is_mark = rows[:, K.K_KIND] == K.KIND_MARK
-                text_rows_list.append(rows[~is_mark])
-                mark_rows_list.append(rows[is_mark])
-                text_pos_list.append(rp[~is_mark])
-                mark_pos_list.append(rp[is_mark])
-            sorted_prep = prepare_sorted_batch(
-                text_rows_list,
-                max_run=0,
-                fallback_max_rounds=int(
-                    os.environ.get("PERITEXT_SORTED_MAX_ROUNDS", "8")
-                ),
-                pos_list=text_pos_list,
-                restack_on_fallback=False,
-            )
-            multi_need = self._multi_group_need(mark_rows_list)
+            # The first half of this path's ingest.stage; _patched_sorted
+            # pads and gathers in the second.
+            with telemetry.span("ingest.stage"):
+                text_rows_list: List[np.ndarray] = []
+                mark_rows_list: List[np.ndarray] = []
+                text_pos_list: List[np.ndarray] = []
+                mark_pos_list: List[np.ndarray] = []
+                for g in groups:
+                    rows = g["rows"]
+                    rp = np.asarray(g["row_pos"])
+                    is_mark = rows[:, K.K_KIND] == K.KIND_MARK
+                    text_rows_list.append(rows[~is_mark])
+                    mark_rows_list.append(rows[is_mark])
+                    text_pos_list.append(rp[~is_mark])
+                    mark_pos_list.append(rp[is_mark])
+                sorted_prep = prepare_sorted_batch(
+                    text_rows_list,
+                    max_run=0,
+                    fallback_max_rounds=int(
+                        os.environ.get("PERITEXT_SORTED_MAX_ROUNDS", "8")
+                    ),
+                    pos_list=text_pos_list,
+                    restack_on_fallback=False,
+                )
+                multi_need = self._multi_group_need(mark_rows_list)
             if sorted_prep["fell_back"]:
                 use_scan = True
                 self.stats["scan_fallbacks"] += 1
@@ -2239,7 +2219,6 @@ class TpuUniverse:
                 group_sizes,
                 multi_need,
                 with_positions=with_positions,
-                wplan=self._window_plan(prep),
             )
         return self._patched_scan(
             prep, host_patches_for, group_sizes, max_rows, with_positions=with_positions
@@ -2252,12 +2231,14 @@ class TpuUniverse:
         op; the reference's asymptotics, kept as the deep-batch fallback
         and the PERITEXT_PATCH_PATH=scan differential leg)."""
         groups, group_of = prep["groups"], prep["group_of"]
-        pad = bucket_length(max_rows)
-        g_ops = np.stack([pad_rows(g["rows"], pad) for g in groups])
-        ops = g_ops[group_of]
-        d_ops = self._put(ops)
-        ranks = self._ranks_jax()
-        multi = _multi_jax(self._rep_sharding)
+        with telemetry.span("ingest.stage"):
+            pad = bucket_length(max_rows)
+            g_ops = np.stack([pad_rows(g["rows"], pad) for g in groups])
+            ops = g_ops[group_of]
+        with telemetry.span("ingest.put"):
+            d_ops = self._put(ops)
+            ranks = self._ranks_jax()
+            multi = _multi_jax(self._rep_sharding)
         pad_per_group = (g_ops[:, :, K.K_KIND] == K.KIND_PAD).sum(axis=1)
         self.stats["rows_padded"] += int((pad_per_group * group_sizes).sum())
 
@@ -2374,7 +2355,6 @@ class TpuUniverse:
         sizes,
         multi_need: int = 0,
         with_positions: bool = False,
-        wplan: Optional[Dict[str, Any]] = None,
     ):
         """The patch-emitting sorted merge: placement rounds + mark-only
         scan + analytic text records (kernels.merge_step_sorted_patched).
@@ -2411,49 +2391,52 @@ class TpuUniverse:
             schema.MAX_MARK_TYPES,
         )
 
-        mark_pad = bucket_length(
-            max(max((m.shape[0] for m in mark_rows_list), default=1), 1)
-        )
-        g_mark = np.stack([pad_rows(m, mark_pad) for m in mark_rows_list])
-        g_mark_pos = np.stack(
-            [
-                np.pad(
-                    p.astype(np.int64),
-                    (0, mark_pad - p.shape[0]),
-                    constant_values=TIME_PAD,
-                )
-                for p in mark_pos_list
-            ]
-        ).astype(np.int32)
+        with telemetry.span("ingest.stage"):
+            mark_pad = bucket_length(
+                max(max((m.shape[0] for m in mark_rows_list), default=1), 1)
+            )
+            g_mark = np.stack([pad_rows(m, mark_pad) for m in mark_rows_list])
+            g_mark_pos = np.stack(
+                [
+                    np.pad(
+                        p.astype(np.int64),
+                        (0, mark_pad - p.shape[0]),
+                        constant_values=TIME_PAD,
+                    )
+                    for p in mark_pos_list
+                ]
+            ).astype(np.int32)
 
-        text_ops = sorted_prep["text"][group_of]
-        rounds = sorted_prep["rounds"][group_of]
-        bufs = sorted_prep["bufs"][group_of]
-        text_pos = sorted_prep["text_pos"][group_of]
-        mark_ops = g_mark[group_of]
-        mark_pos = g_mark_pos[group_of]
-        ranks = self._ranks_jax()
-        multi = _multi_jax(self._rep_sharding)
+            text_ops = sorted_prep["text"][group_of]
+            rounds = sorted_prep["rounds"][group_of]
+            bufs = sorted_prep["bufs"][group_of]
+            text_pos = sorted_prep["text_pos"][group_of]
+            mark_ops = g_mark[group_of]
+            mark_pos = g_mark_pos[group_of]
+        wplan = self._window_plan(prep)
         # ONE batched host->device transfer for the whole launch's op
         # tensors (a device_put per array cost ~0.1ms fixed overhead each
         # on the build box — at windowed single-op latencies that was the
         # dominant term); retries and overflow relaunches reuse the same
         # device arrays.
-        if wplan is not None:
-            (
-                d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos,
-                d_wstart, d_whull, d_wvb, d_wva,
-            ) = self._put(
+        with telemetry.span("ingest.put"):
+            ranks = self._ranks_jax()
+            multi = _multi_jax(self._rep_sharding)
+            if wplan is not None:
                 (
-                    text_ops, rounds, bufs, text_pos, mark_ops, mark_pos,
-                    wplan["starts"], wplan["hulls"], wplan["vis_base"],
-                    wplan["vis_after"],
+                    d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos,
+                    d_wstart, d_whull, d_wvb, d_wva,
+                ) = self._put(
+                    (
+                        text_ops, rounds, bufs, text_pos, mark_ops, mark_pos,
+                        wplan["starts"], wplan["hulls"], wplan["vis_base"],
+                        wplan["vis_after"],
+                    )
                 )
-            )
-        else:
-            d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos = self._put(
-                (text_ops, rounds, bufs, text_pos, mark_ops, mark_pos)
-            )
+            else:
+                d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos = self._put(
+                    (text_ops, rounds, bufs, text_pos, mark_ops, mark_pos)
+                )
         pad_per_group = (sorted_prep["text"][:, :, K.K_KIND] == K.KIND_PAD).sum(
             axis=1
         ) + (g_mark[:, :, K.K_KIND] == K.KIND_PAD).sum(axis=1)

@@ -17,7 +17,9 @@ reports through:
   monotonic microsecond timestamps and the recording thread's id, so
   nested spans render as flame stacks per thread in Perfetto /
   chrome://tracing.  Every span also lands in the registry as a
-  ``span.<name>.seconds`` histogram;
+  ``span.<name>.seconds`` histogram, and, under a ``jax.profiler``
+  session, on the profiler's ``/host:CPU`` plane as an event of the same
+  name, on the clock of the device's ``XLA Ops``;
 - a **causal flow plane**: a :class:`TraceContext` (monotonic id — no
   wall-clock entropy) is minted where a change enters the system
   (``ChangeQueue.enqueue``, ``TpuDoc.change``, ``Publisher.publish``,
@@ -104,8 +106,8 @@ allocates nothing.  tests/test_telemetry.py pins both properties.
 
 Enabled, the cost is one small dict update under a lock per event —
 instrumentation is launch-level (per kernel launch / flush / cohort),
-never per-op, so a telemetry-on run stays within a couple percent of
-telemetry-off on the patched-fleet steady state.
+never per-op; a span adds a profiler annotation (a few hundred ns with
+no profiler session).
 
 Thread safety: all registry mutation happens under one lock (concurrent
 ``ChangeQueue`` timer flushes and foreground ingest cannot lose
@@ -431,19 +433,32 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0")
+    """An enabled span.  Besides the registry histogram and the JSONL
+    tracer it enters a ``jax.profiler.TraceAnnotation`` of the same bare
+    name, so under a profiler session the span lands on the host plane of
+    the ``.xplane.pb`` on the device trace's clock (a cheap no-op with no
+    session)."""
+
+    __slots__ = ("name", "args", "_t0", "_ann")
 
     def __init__(self, name: str, args: Optional[Dict[str, Any]]) -> None:
         self.name = name
         self.args = args
         self._t0 = 0
+        self._ann: Any = None
 
     def __enter__(self) -> "_Span":
+        annotation = _annotation
+        if annotation is not None:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         # The span may outlive a disable() (e.g. a test tearing down while a
         # timer-thread flush is mid-span); record into whatever plane is
         # current — the registry/tracer never become invalid, only unused.
@@ -609,6 +624,9 @@ class _MetricsFlusher(threading.Thread):
 
 _registry = Registry()
 _tracer: Optional[_Tracer] = None
+# jax.profiler.TraceAnnotation, resolved by the first enable(): the
+# disabled path never imports jax.
+_annotation: Optional[Callable[[str], Any]] = None
 _metrics_path: Optional[str] = None
 _config_lock = threading.Lock()
 _atexit_registered = False
@@ -1395,9 +1413,15 @@ def enable(
     daemon thread); ``blackbox`` arms failure dumps to a directory;
     ``status_path`` arms the live ops status surface (written on the same
     periodic flusher and at exit).  All may be omitted — a bare
-    ``enable()`` collects registry metrics only."""
+    ``enable()`` collects registry metrics only.  Enabled spans also write
+    into a running ``jax.profiler`` trace."""
     global enabled, _tracer, _metrics_path, _blackbox_dir, _flusher, _status_path
+    global _annotation
     with _config_lock:
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
         if trace:
             if _tracer is not None and _tracer.path != trace:
                 _tracer.close()
