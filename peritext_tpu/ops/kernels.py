@@ -700,10 +700,15 @@ def _apply_text_op(carry, op, ranks, char_buf=None):
 def _slot_permutation(orig_idx):
     """Flat slot-axis form of a text phase's element permutation:
     ``(valid [2C], flat_src [2C])`` mapping each post-splice boundary slot
-    to its pre-splice slot.  THE one definition for every plane that rides
-    the splice (boundary tables, winner cache) — and deliberately flat:
-    a [C, 2, ...]-view gather costs the compiler full-plane layout copies
-    (PROFILE_r05.md)."""
+    to its pre-splice slot, for the paths that hold only a permutation
+    after placement (the scan merge, the Pallas text phase, the
+    sequence-sharded placement, the patch-emitting merge) and re-align
+    their per-slot planes with a gather.  Deliberately flat: a [C, 2,
+    ...]-view gather costs the compiler full-plane layout copies
+    (PROFILE_r05.md).  XLA on the TPU runs such a gather close to one
+    element at a time, which is why the full-table sorted merge moves its
+    boundary tables with the placement's shift passes instead
+    (_place_round)."""
     c = orig_idx.shape[0]
     slots = jnp.arange(2 * c, dtype=jnp.int32)
     valid = (orig_idx >= 0)[slots // 2]
@@ -896,22 +901,30 @@ merge_step_fused_batch = jax.jit(merge_step_fused_vmapped)
 # tombstones) and apply as one [L, C] masked match per round.
 
 
-# Splice strategy for sort-based placement: "sort" (default) materializes
-# each round's output with a stable sort by destination — scatter-free, since
-# XLA lowers generic scatters near-serially (measured 8.9x whole-bench on
-# CPU; scatters are the known slow path on TPU too).  "scatter" keeps the
-# .at[].set splice for A/B.  Read at import/trace time: set PERITEXT_SPLICE
-# before importing (bench A/B runs set it per subprocess).
-_SPLICE_MODE = os.environ.get("PERITEXT_SPLICE", "sort")
-if _SPLICE_MODE not in ("sort", "scatter", "roll"):
-    raise ValueError(
-        f"PERITEXT_SPLICE={_SPLICE_MODE!r}: must be 'sort', 'scatter' or 'roll'"
-    )
+# Splice by shifts.  XLA on the TPU lowers a batched gather (and a generic
+# scatter) with per-element indices close to one element at a time: on a
+# v5e a [5120, 2048] plane gathered by a sort permutation moved ~86 M
+# elements/s, ~0.3 GB/s against 819 GB/s of HBM.  Placement moves every
+# existing element right by a displacement that never decreases along the
+# array (``shifts`` counts the blocks placed at or before it), so the
+# output needs no per-element index at all: MSB-first binary-decomposed
+# static rolls, each fused with a select, move the elements (and every
+# per-slot plane that rides with them, at twice the displacement), and the
+# op blocks fill in from small one-hot reductions over the round's ops.
+# Each pass is a static lane shift plus a select, which the TPU runs at
+# vector and HBM speed.
 
 
 def _place_round(carry, r, ops, round_of, ranks, char_buf, maxk: int):
-    """Apply every round-r text op simultaneously (one scatter pass)."""
-    elem_ctr, elem_act, deleted, chars, orig_idx, length = carry
+    """Apply every round-r text op simultaneously.
+
+    ``carry`` is (elem_ctr, elem_act, deleted, chars, orig_idx, length,
+    slot_planes): ``slot_planes`` holds per-boundary-slot planes ([2C] or
+    [2C, W], slot 2i and 2i+1 belonging to element i) that move with their
+    elements; the slots of inserted and beyond-length elements come out
+    False / 0.
+    """
+    elem_ctr, elem_act, deleted, chars, orig_idx, length, slot_planes = carry
     c = elem_ctr.shape[0]
     ar = jnp.arange(c, dtype=jnp.int32)
     alive = ar < length
@@ -957,9 +970,67 @@ def _place_round(carry, r, ops, round_of, ranks, char_buf, maxk: int):
 
     # Existing elements shift right by every block placed at or before them.
     shifts = jnp.sum(k[:, None] * (t[:, None] <= ar[None, :]).astype(jnp.int32), axis=0)
-    dest_exist = jnp.where(alive, ar + shifts, c)  # dead slots drop
 
-    # Op-block values and destinations, [L, maxk].
+    def per_slot(x):  # [C] -> [2C]: repeat per slot pair, no indexing
+        return jnp.broadcast_to(x[:, None], (c, 2)).reshape(2 * c)
+
+    def lift(cond, plane):  # broadcast a [2C] condition over a slot plane
+        return cond.reshape(cond.shape + (1,) * (plane.ndim - 1))
+
+    # Move existing elements right by their displacement: one static roll +
+    # select per bit, MSB first.
+    #
+    # Correctness of the greedy bit decomposition: displacements are
+    # non-decreasing along positions (shifts is a cumulative count), and
+    # MSB-first keeps every alive remainder below the current step's
+    # doubled width; if a mover (rem >= step) could land on an alive
+    # slower value (0 < rem < step), the two remainders mod 2*step
+    # would have to differ by more than the values' destination gap
+    # allows — impossible for monotone displacements (proof by the
+    # mod-2^(b+1) window: rem_X >= 2^b, delta <= 2^b - 2 forces
+    # rem_Y = rem_X + delta mod 2^(b+1) >= 2^b, a contradiction).
+    # Stale source copies are marked DEAD (-1) so they never move again;
+    # every position below the new length is re-covered by a mover, a
+    # block lane, or its unmoved occupant, and the tail is masked to the
+    # fills.  Slot planes take the same selects at twice the shift.  Bool
+    # planes ride the passes as int32: on the CPU backend a rolled pred
+    # plane adds conversion kernels to every pass.
+    rem = shifts  # [C]; beyond-length lanes inherit the running count
+    planes = [elem_ctr, elem_act, deleted.astype(jnp.int32), chars, orig_idx]
+    slot_dtypes = [p.dtype for p in slot_planes]
+    slot_planes = [
+        p.astype(jnp.int32) if p.dtype == jnp.bool_ else p for p in slot_planes
+    ]
+    max_disp = ops.shape[0] * maxk
+    for b in reversed(range(max_disp.bit_length())):
+        step = 1 << b
+        moved_rem = jnp.roll(rem, step)
+        sel = (moved_rem >= step) & (ar >= step)  # block wraparound
+        planes = [jnp.where(sel, jnp.roll(p, step), p) for p in planes]
+        sel2 = per_slot(sel)
+        slot_planes = [
+            jnp.where(lift(sel2, p), jnp.roll(p, 2 * step, axis=0), p)
+            for p in slot_planes
+        ]
+        rem = jnp.where(sel, moved_rem - step, jnp.where(rem >= step, -1, rem))
+
+    # Block fill: each output position belongs to at most one op block
+    # (destinations are unique), so masked int max-reductions are exact.
+    in_blk = (
+        is_ins[:, None]
+        & (ar[None, :] >= s[:, None])
+        & (ar[None, :] < (s + k)[:, None])
+    )  # [L, C]
+    neg = jnp.int32(-(2**31) + 1)
+    blk_any = in_blk.any(axis=0)
+
+    def from_ops(vals_l):  # [L] -> [C] masked max over the owning op
+        return jnp.max(jnp.where(in_blk, vals_l[:, None], neg), axis=0)
+
+    blk_ctr = from_ops(ctr_i - s) + ar  # ctr_l + (p - s_l)
+    blk_act = from_ops(ops[:, K_ACT])
+    # Run chars via the [L*maxk] block-lane one-hot (payload chars for
+    # plain inserts ride the same table).
     off = jnp.arange(maxk, dtype=jnp.int32)
     in_block = (off[None, :] < k[:, None]) & is_ins[:, None]
     dest_ops = jnp.where(in_block, s[:, None] + off[None, :], c)
@@ -967,164 +1038,59 @@ def _place_round(carry, r, ops, round_of, ranks, char_buf, maxk: int):
     block_chars = jnp.where(
         is_run[:, None], char_buf[buf_idx], ops[:, K_PAYLOAD, None]
     )
-    block_ctr = ctr_i[:, None] + off[None, :]
-    block_act = jnp.broadcast_to(ops[:, K_ACT, None], (ops.shape[0], maxk))
-
-    zero_blk = jnp.zeros_like(block_ctr)
-    new_length = length + jnp.sum(k)
-    if _SPLICE_MODE == "roll":
-        # Roll splice: move existing elements right by their displacement
-        # with MSB-first binary-decomposed rolls, then overwrite block
-        # positions from small one-hot reductions.  ceil(log2(L*maxk+1))
-        # roll+select passes over [C] planes — cheaper than a bitonic sort
-        # over C+L*maxk lanes, and scatter-free.
-        #
-        # Correctness of the greedy bit decomposition: displacements are
-        # non-decreasing along positions (shifts is a cumulative count), and
-        # MSB-first keeps every alive remainder below the current step's
-        # doubled width; if a mover (rem >= step) could land on an alive
-        # slower value (0 < rem < step), the two remainders mod 2*step
-        # would have to differ by more than the values' destination gap
-        # allows — impossible for monotone displacements (proof by the
-        # mod-2^(b+1) window: rem_X >= 2^b, delta <= 2^b - 2 forces
-        # rem_Y = rem_X + delta mod 2^(b+1) >= 2^b, a contradiction).
-        # Stale source copies are marked DEAD (-1) so they never move again;
-        # every position below the new length is re-covered by a mover, a
-        # block lane, or its unmoved occupant, and the tail is masked to
-        # the scatter fills.
-        rem = shifts  # [C]; beyond-length lanes inherit the running count
-        planes = [
-            elem_ctr,
-            elem_act,
-            deleted.astype(jnp.int32),
-            chars,
-            orig_idx,
-        ]
-        max_disp = ops.shape[0] * maxk
-        for b in reversed(range(max_disp.bit_length())):
-            step = 1 << b
-            moved_rem = jnp.roll(rem, step)
-            sel = (moved_rem >= step) & (ar >= step)  # block wraparound
-            planes = [
-                jnp.where(sel, jnp.roll(p, step), p) for p in planes
-            ]
-            rem = jnp.where(sel, moved_rem - step, jnp.where(rem >= step, -1, rem))
-
-        # Block fill: each output position belongs to at most one op block
-        # (destinations are unique), so masked int max-reductions are exact.
-        in_blk = (
-            is_ins[:, None]
-            & (ar[None, :] >= s[:, None])
-            & (ar[None, :] < (s + k)[:, None])
-        )  # [L, C]
-        neg = jnp.int32(-(2**31) + 1)
-        blk_any = in_blk.any(axis=0)
-
-        def from_ops(vals_l):  # [L] -> [C] masked max over the owning op
-            return jnp.max(jnp.where(in_blk, vals_l[:, None], neg), axis=0)
-
-        blk_ctr = from_ops(ctr_i - s) + ar  # ctr_l + (p - s_l)
-        blk_act = from_ops(ops[:, K_ACT])
-        # Run chars via the [L*maxk] block-lane one-hot (payload chars for
-        # plain inserts ride the same table).
-        lane_hit = (
-            in_block.reshape(-1)[:, None]
-            & (dest_ops.reshape(-1)[:, None] == ar[None, :])
-        )  # [L*maxk, C]
-        blk_char = jnp.max(
-            jnp.where(lane_hit, block_chars.reshape(-1)[:, None], neg), axis=0
-        )
-
-        live_out = ar < new_length
-        outs = [
-            jnp.where(blk_any, blk_ctr, planes[0]),
-            jnp.where(blk_any, blk_act, planes[1]),
-            jnp.where(blk_any, 0, planes[2]),
-            jnp.where(blk_any, blk_char, planes[3]),
-            jnp.where(blk_any, -1, planes[4]),
-        ]
-        fills = (0, 0, 0, 0, -1)
-        outs = [jnp.where(live_out, o, f) for o, f in zip(outs, fills)]
-        return (
-            outs[0],
-            outs[1],
-            outs[2].astype(bool),
-            outs[3],
-            outs[4],
-            new_length,
-        )
-    if _SPLICE_MODE == "sort":
-        # Scatter-free splice: XLA:TPU lowers generic scatters to a
-        # near-serial loop over indices, which dominates the whole merge on
-        # hardware.  Destinations are unique, so materializing the output is
-        # a stable sort by destination — but only the (dest, lane-id) pair
-        # rides the bitonic network; the five payload planes are GATHERED
-        # once by the resulting permutation instead of being dragged
-        # through every compare-exchange stage (argsort+gather: ~2 planes x
-        # log^2(n) passes + 5 one-pass gathers, vs 6 planes x log^2(n)).
-        # State-identical to the scatter splice (same suites cover both;
-        # PERITEXT_SPLICE selects).
-        keys = jnp.concatenate([dest_exist, dest_ops.reshape(-1)])
-        take = jnp.argsort(keys, stable=True)[:c]
-        planes = [
-            (jnp.concatenate([elem_ctr, block_ctr.reshape(-1)]), 0),
-            (jnp.concatenate([elem_act, block_act.reshape(-1)]), 0),
-            (
-                jnp.concatenate([deleted.astype(jnp.int32), zero_blk.reshape(-1)]),
-                0,
-            ),
-            (jnp.concatenate([chars, block_chars.reshape(-1)]), 0),
-            (jnp.concatenate([orig_idx, zero_blk.reshape(-1) - 1]), -1),
-        ]
-        live_out = ar < new_length
-        outs = [
-            jnp.where(live_out, plane[take], fill) for plane, fill in planes
-        ]
-        new_carry = (
-            outs[0],
-            outs[1],
-            outs[2].astype(bool),
-            outs[3],
-            outs[4],
-            new_length,
-        )
-        return new_carry
-
-    def scat(exist_vals, op_vals, fill):
-        out = jnp.full(c, fill, exist_vals.dtype)
-        out = out.at[dest_exist].set(exist_vals, mode="drop")
-        return out.at[dest_ops].set(op_vals, mode="drop")
-
-    new_carry = (
-        scat(elem_ctr, block_ctr, 0),
-        scat(elem_act, block_act, 0),
-        scat(deleted.astype(jnp.int32), zero_blk, 0).astype(bool),
-        scat(chars, block_chars, 0),
-        scat(orig_idx, zero_blk - 1, -1),
-        new_length,
+    lane_hit = (
+        in_block.reshape(-1)[:, None]
+        & (dest_ops.reshape(-1)[:, None] == ar[None, :])
+    )  # [L*maxk, C]
+    blk_char = jnp.max(
+        jnp.where(lane_hit, block_chars.reshape(-1)[:, None], neg), axis=0
     )
-    return new_carry
+
+    new_length = length + jnp.sum(k)
+    live_out = ar < new_length
+    outs = [
+        jnp.where(blk_any, blk_ctr, planes[0]),
+        jnp.where(blk_any, blk_act, planes[1]),
+        jnp.where(blk_any, 0, planes[2]),
+        jnp.where(blk_any, blk_char, planes[3]),
+        jnp.where(blk_any, -1, planes[4]),
+    ]
+    fills = (0, 0, 0, 0, -1)
+    outs = [jnp.where(live_out, o, f) for o, f in zip(outs, fills)]
+    outs[2] = outs[2].astype(bool)
+    slot_kept = per_slot(live_out & ~blk_any)
+    slot_planes = tuple(
+        jnp.where(lift(slot_kept, p), p, jnp.zeros((), p.dtype)).astype(dt)
+        for p, dt in zip(slot_planes, slot_dtypes)
+    )
+    return (*outs, new_length, slot_planes)
 
 
 def place_text_batch(
     elem_ctr, elem_act, deleted, chars, length, text_ops, round_of, num_rounds,
-    ranks, char_buf, maxk: int,
+    ranks, char_buf, maxk: int, slot_planes=(),
 ):
     """Integrate a causally-ordered text-op batch in ``num_rounds`` rounds.
 
-    Returns the updated element arrays plus the orig-index permutation plane
-    (for boundary realignment, as in the two-phase path).  ``num_rounds`` is
-    a traced scalar — one compiled program serves any batch depth.
+    Returns ``(elem_ctr, elem_act, deleted, chars, orig_idx, length,
+    *slot_planes)``: the updated element arrays, the orig-index permutation
+    plane (each element's pre-batch position, -1 for batch-born ones), and
+    the given per-slot planes (the boundary tables) moved with their
+    elements.  ``num_rounds`` is a traced scalar — one compiled program
+    serves any batch depth.
     """
     c = elem_ctr.shape[0]
-    carry = (elem_ctr, elem_act, deleted, chars, jnp.arange(c, dtype=jnp.int32), length)
+    carry = (
+        elem_ctr, elem_act, deleted, chars, jnp.arange(c, dtype=jnp.int32), length,
+        tuple(slot_planes),
+    )
     carry = lax.fori_loop(
         0,
         num_rounds,
         lambda r, cry: _place_round(cry, r, text_ops, round_of, ranks, char_buf, maxk),
         carry,
     )
-    return carry
+    return carry[:-1] + carry[-1]
 
 
 # Batched mark application.  Sequential dependence between mark ops comes
@@ -1204,7 +1170,6 @@ def _or_accumulate(mask: jax.Array, bit_rows: jax.Array) -> jax.Array:
 
 def _apply_marks_batch(
     bnd_def, bnd_mask, mark_ops, elem_ctr, elem_act, length, mark_count, w_words,
-    perm=None,
 ):
     """Apply a causally-ordered mark-op batch to the boundary tables at once.
 
@@ -1216,36 +1181,17 @@ def _apply_marks_batch(
     Bit-exact with scanning _apply_mark_fast over the same rows (differential
     coverage in tests/test_sorted_merge.py).  Returns (bnd_def, bnd_mask).
 
-    ``perm`` (the text phase's orig-index plane) composes the post-splice
-    boundary permutation INTO this phase's reads instead of materializing a
-    permuted [2C, W] plane first (_permute_boundaries): every access to the
-    old tables goes through ``old_rows``/``def_p``, so the full-width plane
-    is read once and written once for the whole phase.
+    The tables come in already aligned with the element planes (the
+    placement moves them with its shift passes), so every per-slot read is
+    the plane itself: nothing here indexes a [2C] plane by a [2C] index.
+    Lookups into the batch's small tables (per op, per write node) expand
+    to slots as static select chains, not gathers.
     """
     m_ops = mark_ops.shape[0]
     c = elem_ctr.shape[0]
     two_c = 2 * c
     midx = jnp.arange(m_ops, dtype=jnp.int32)
     slots = jnp.arange(two_c, dtype=jnp.int32)
-
-    if perm is not None:
-        # Flat slot-axis composition (post-splice slot -> pre-splice slot):
-        # one single-axis gather per use (_slot_permutation).
-        pvalid, pflat = _slot_permutation(perm)
-        def_p = jnp.where(pvalid, bnd_def[pflat], False)
-
-        def old_rows(slot_idx):  # [N] post-splice slots -> [N, W] old rows
-            return jnp.where(
-                pvalid[slot_idx][:, None],
-                bnd_mask[pflat[slot_idx]],
-                jnp.uint32(0),
-            )
-
-    else:
-        def_p = bnd_def
-
-        def old_rows(slot_idx):
-            return bnd_mask[slot_idx]
 
     # Anchor resolution (same rules as _apply_mark_fast, batched).
     valid, s_slot, e_slot = _batched_anchor_slots(mark_ops, elem_ctr, elem_act, length)
@@ -1273,7 +1219,7 @@ def _apply_marks_batch(
         jnp.uint32(0),
     )  # [M, w_act]
 
-    d0 = def_p & (slots < 2 * length)  # defined before the batch
+    d0 = bnd_def & (slots < 2 * length)  # defined before the batch
 
     writes_s = valid & (s_slot < e_slot)
     writes_e = valid & (e_slot < two_c)
@@ -1281,7 +1227,9 @@ def _apply_marks_batch(
     WE = writes_e[:, None] & (slots[None, :] == e_slot[:, None])
     w_any = WS | WE
     written_any = w_any.any(axis=0)  # [2C]
-    w_last = jnp.max(jnp.where(w_any, midx[:, None], -1), axis=0)  # [2C]
+    ws_last = jnp.max(jnp.where(WS, midx[:, None], -1), axis=0)  # [2C]
+    we_last = jnp.max(jnp.where(WE, midx[:, None], -1), axis=0)
+    w_last = jnp.maximum(ws_last, we_last)
     f_first = jnp.min(jnp.where(w_any, midx[:, None], m_ops), axis=0)
     # First time each slot is defined: -1 = pre-batch, m_ops+1 = never.
     def_time = jnp.where(
@@ -1313,7 +1261,7 @@ def _apply_marks_batch(
         # (full-width — pre-batch bits live anywhere in the table).
         root_row = jnp.where(
             ((prev < 0) & (q >= 0))[:, None] & d0[qc][:, None],
-            old_rows(qc),
+            bnd_mask[qc],
             jnp.uint32(0),
         )
         return q, prev, seg_bits, root_row
@@ -1349,36 +1297,35 @@ def _apply_marks_batch(
     # their writer's root row (replacing the old row in every word);
     # everything else keeps its old row.  Window pass: the batch's new bits
     # (rebase chain + tail) OR into the w_act active words only.
-    wl = jnp.maximum(w_last, 0)
-    node_at = jnp.where(s_slot[wl] == slots, wl, wl + m_ops)
+    # A slot's writer node: the last op writing it, as its S node when that
+    # op wrote it as its start (an op never writes one slot as both).
+    node_at = jnp.where(ws_last > we_last, ws_last, we_last + m_ops)
     written_col = written_any[:, None]
-    # Expand the tiny [2M, W] root table to written slots as a static OR-
-    # select chain instead of a [2C]-index gather: the chain stays inside
-    # the one full-plane output fusion (a gather materializes its own
-    # [2C, W] plane), and the merge is bandwidth-bound ~300:1, so the
-    # extra 2M broadcast selects are free VPU work.  Guarded: HLO size and
-    # trace time scale with the (padded) node count, so unusually deep
-    # mark batches fall back to the gather.
+    # Expand the tiny [2M, .] node tables to written slots as static OR-
+    # select chains instead of [2C]-index gathers: the chains stay inside
+    # the one full-plane output fusion, where a gather would run close to
+    # one element at a time and materialize its own [2C, .] plane.
+    # Guarded: HLO size and trace time scale with the (padded) node count,
+    # so unusually deep mark batches fall back to the gathers.
     if n_nodes <= 128:
         root_at = jnp.uint32(0)
+        win_at = jnp.uint32(0)
         for n in range(n_nodes):
-            root_at = root_at | jnp.where(
-                (node_at == n)[:, None], acc_root[n], jnp.uint32(0)
-            )
+            hit = (node_at == n)[:, None]
+            root_at = root_at | jnp.where(hit, acc_root[n], jnp.uint32(0))
+            win_at = win_at | jnp.where(hit, acc_win[n], jnp.uint32(0))
     else:
         root_at = acc_root[node_at]
-    base_full = jnp.where(written_col, root_at, old_rows(slots))
+        win_at = acc_win[node_at]
+    base_full = jnp.where(written_col, root_at, bnd_mask)
     start_time = jnp.where(written_any, w_last, -1)
     tail_mask = in_range_t & (midx[None, :] > start_time[:, None])  # [2C, M]
     tail_w = _or_accumulate(tail_mask, B)  # [2C, w_act]
     # Tail bits apply to written rows and to pre-defined rows only (the
     # walk never marks undefined slots) — the old full-width `touched`
-    # gate, expressed per window word.  The window delta is scattered back
-    # over the word axis with a broadcast compare + tiny-axis gather (both
-    # fuse into the single full-plane output pass; a dynamic_update_slice
-    # here costs full-plane layout copies instead).
+    # gate, expressed per window word.
     delta = (
-        jnp.where(written_col, acc_win[node_at], jnp.uint32(0))
+        jnp.where(written_col, win_at, jnp.uint32(0))
         | jnp.where(written_col | d0[:, None], tail_w, jnp.uint32(0))
     )  # [2C, w_act]
     # Scatter the window back over the word axis as w_act static broadcast-
@@ -1392,7 +1339,7 @@ def _apply_marks_batch(
             word_full[None, :] == w0 + j, delta[:, j][:, None], jnp.uint32(0)
         )
     new_mask = base_full | expanded
-    new_def = def_p | written_any
+    new_def = bnd_def | written_any
     return new_def, new_mask
 
 
@@ -1417,21 +1364,21 @@ def _append_mark_table(state_fields, mark_ops, mark_count, m_cap):
 
 
 def _sorted_tail(
-    state: DocState, elem_ctr, elem_act, deleted, chars, orig_idx, length, mark_ops
+    state: DocState, elem_ctr, elem_act, deleted, chars, length, bnd_def, bnd_mask,
+    mark_ops,
 ) -> DocState:
     """Post-placement tail shared by the sorted merges: batched mark phase
-    (with the boundary permute composed into its reads) + table append, per
-    replica."""
+    over boundary tables already aligned with the placed elements, plus the
+    table append, per replica."""
     bnd_def, bnd_mask = _apply_marks_batch(
-        state.bnd_def,
-        state.bnd_mask,
+        bnd_def,
+        bnd_mask,
         mark_ops,
         elem_ctr,
         elem_act,
         length,
         state.mark_count,
         state.bnd_mask.shape[-1],
-        perm=orig_idx,
     )
     mark_ctr, mark_act, mark_action, mark_type, mark_attr, mark_count = _append_mark_table(
         (state.mark_ctr, state.mark_act, state.mark_action, state.mark_type, state.mark_attr),
@@ -1473,7 +1420,7 @@ def merge_step_sorted(
     the mark phase costs O(log #marks) gather rounds instead of one scan
     step per mark op.
     """
-    elem_ctr, elem_act, deleted, chars, orig_idx, length = place_text_batch(
+    elem_ctr, elem_act, deleted, chars, _, length, bnd_def, bnd_mask = place_text_batch(
         state.elem_ctr,
         state.elem_act,
         state.deleted,
@@ -1485,9 +1432,10 @@ def merge_step_sorted(
         ranks,
         char_buf,
         maxk,
+        slot_planes=(state.bnd_def, state.bnd_mask),
     )
     return _sorted_tail(
-        state, elem_ctr, elem_act, deleted, chars, orig_idx, length, mark_ops
+        state, elem_ctr, elem_act, deleted, chars, length, bnd_def, bnd_mask, mark_ops
     )
 
 
@@ -1678,7 +1626,7 @@ def _sorted_def_first(bnd_def0, mark_ops, elem_ctr, elem_act, length):
 # in practice the add and removes of one comment id) the cached patch scan
 # resolves exactly.  The universe checks group sizes host-side and falls
 # back to the interleaved scan when exceeded, so the cap never silently
-# changes results.  Read at import time, like PERITEXT_SPLICE.
+# changes results.  Read at import time.
 PATCH_GROUP_K = int(os.environ.get("PERITEXT_PATCH_GROUP_K", "32"))
 
 
@@ -1910,8 +1858,8 @@ def _delta_mark_scan(
     is_multi_v = multi[tau_v]
 
     # The text phase's boundary permutation composes INTO every base-plane
-    # read (the _apply_marks_batch `perm=` trick): with ``perm`` given,
-    # ``bnd_mask_base`` AND ``wcache0`` are the RAW pre-splice planes and
+    # read: with ``perm`` given, ``bnd_mask_base`` AND ``wcache0`` are the
+    # RAW pre-splice planes and
     # no permuted [2C, W] / [2C, T, 4] copy is ever materialized — both
     # planes are only read through composed gathers and written once by
     # the final composes.
@@ -3007,8 +2955,8 @@ def _scatter_window(state: DocState, win: DocState, start, hull_len) -> DocState
     Elements [0, start) keep their rows, [start, start + win.length) come
     from the window, and the pre-batch tail shifts right by the insert
     count (win.length - hull_len).  Slots at or beyond the new length are
-    masked to the dead-slot fills — the same convention the sort splice
-    leaves behind — so a windowed and a full-table merge of the same batch
+    masked to the dead-slot fills — the same convention the placement's
+    shift splice leaves behind — so a windowed and a full-table merge of the same batch
     produce byte-identical planes."""
     c = state.capacity
     w_cap = win.capacity

@@ -303,9 +303,12 @@ def merge_step_sorted_sp(mesh: Mesh, halo: int, maxk: int):
             ranks,
             char_buf,
         )
-        return jax.vmap(
-            K._sorted_tail, in_axes=(0, 0, 0, 0, 0, 0, 0, 0)
-        )(states, ec, ea, dl.astype(bool), ch, oi, ln, mark_ops)
+        bnd_def, bnd_mask = jax.vmap(K._permute_boundaries)(
+            states.bnd_def, states.bnd_mask, oi
+        )
+        return jax.vmap(K._sorted_tail)(
+            states, ec, ea, dl.astype(bool), ch, ln, bnd_def, bnd_mask, mark_ops
+        )
 
     return jax.jit(step)
 

@@ -212,10 +212,9 @@ def main() -> int:
     if want("tail"):
         def tail_fn(st, m, rk):
             def one(s, mm):
-                c = s.elem_ctr.shape[0]
-                orig = jnp.arange(c, dtype=jnp.int32)
                 return K._sorted_tail(
-                    s, s.elem_ctr, s.elem_act, s.deleted, s.chars, orig, s.length, mm
+                    s, s.elem_ctr, s.elem_act, s.deleted, s.chars, s.length,
+                    s.bnd_def, s.bnd_mask, mm,
                 )
 
             return jax.vmap(one)(st, m)

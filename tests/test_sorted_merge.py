@@ -211,37 +211,185 @@ def test_chunked_sorted_merge_matches_unchunked():
     assert_states_equal(ref, out, "chunked")
 
 
-def test_scatter_splice_matches_sort_splice(monkeypatch):
-    """Both splice strategies (PERITEXT_SPLICE) produce identical states.
+def _text_op(action, **kw):
+    return {"path": ["text"], "action": action, **kw}
 
-    The module default is "sort"; the scatter branch is the A/B fallback and
-    must not rot.  _SPLICE_MODE is read at trace time, so patching the module
-    global and calling the unjitted merge covers the scatter branch.
-    """
-    workload = make_merge_workload(
-        doc_len=60, ops_per_merge=24, num_streams=3, with_marks=True, seed=11
+
+def _ins(index, chars):
+    return _text_op("insert", index=index, values=list(chars))
+
+
+def _mark(action, start, end, mark_type, attrs=None):
+    op = _text_op(
+        action, startIndex=start, endIndex=end, markType=mark_type
     )
-    batch = build_device_batch(workload, num_replicas=3, capacity=128, max_mark_ops=64)
-    text, ro, nr, buf, maxk = sorted_inputs(
-        [np.asarray(batch["text_ops"][r]) for r in range(3)]
+    if attrs is not None:
+        op["attrs"] = attrs
+    return op
+
+
+def _genesis(doc, text):
+    change, _ = doc.change(
+        [{"path": [], "action": "makeList", "key": "text"}, _ins(0, text)]
     )
-    mark_ops = jnp.asarray(batch["mark_ops"])
-    ranks = jnp.asarray(batch["ranks"])
+    return change
 
-    def run():
-        import jax
 
-        return jax.vmap(
-            lambda st, t, r, m, b: K.merge_step_sorted(
-                st, t, r, jnp.int32(nr), m, ranks, b, maxk=maxk
+def _writers(names, base_changes):
+    docs = [Doc(n) for n in names]
+    for d in docs:
+        for ch in base_changes:
+            d.apply_change(ch)
+    return docs
+
+
+def _case_concurrent_marks():
+    """Two concurrent streams with inserts, a delete and marks, over a doc
+    that already holds marks: one insert lands on a mark's start boundary,
+    so that defined slot moves and the new elements' slots start empty."""
+    base = Doc("base")
+    g = _genesis(base, "The quick brown fox jumps over the lazy dog")
+    m, _ = base.change([_mark("addMark", 4, 15, "strong")])
+    alice, bob = _writers(("alice", "bob"), [g, m])
+    a1, _ = alice.change([_ins(10, "AAA"), _mark("addMark", 2, 20, "em")])
+    a2, _ = alice.change([_mark("removeMark", 5, 8, "strong")])
+    b1, _ = bob.change([_ins(4, "BB"), _text_op("delete", index=30, count=3)])
+    b2, _ = bob.change([_mark("addMark", 0, 12, "link", {"url": "b.com"})])
+    return [g, m], [a1, a2, b1, b2]
+
+
+def _case_chained_rounds():
+    """Inserts referencing the batch's own inserts: several placement
+    rounds, each moving the boundary tables again."""
+    base = Doc("base")
+    g = _genesis(base, "0123456789abcdef")
+    m, _ = base.change([_mark("addMark", 2, 12, "em")])
+    (w,) = _writers(("w",), [g, m])
+    c1, _ = w.change([_ins(5, "abc")])
+    c2, _ = w.change([_ins(8, "def")])
+    c3, _ = w.change([_ins(0, "Z"), _ins(12, "gh"), _mark("addMark", 5, 13, "strong")])
+    return [g, m], [c1, c2, c3]
+
+
+def _case_genesis():
+    """The genesis change and its first marks merged into an empty doc."""
+    w = Doc("w")
+    g = _genesis(w, "hello brave new world")
+    c1, _ = w.change(
+        [_mark("addMark", 0, 5, "strong"), _mark("addMark", 6, 21, "comment", {"id": "c1"})]
+    )
+    c2, _ = w.change([_ins(5, ","), _mark("removeMark", 2, 4, "strong")])
+    return [], [g, c1, c2]
+
+
+def _case_tombstones():
+    """Most of the doc is tombstones: inserts and mark anchors land among
+    deleted elements, whose boundary slots still carry pre-batch marks."""
+    base = Doc("base")
+    g = _genesis(base, "".join(chr(97 + i % 26) for i in range(80)))
+    m, _ = base.change([_mark("addMark", 10, 50, "strong")])
+    d, _ = base.change([_text_op("delete", index=5, count=60)])
+    alice, bob = _writers(("alice", "bob"), [g, m, d])
+    a1, _ = alice.change([_ins(3, "xyz"), _text_op("delete", index=8, count=2)])
+    a2, _ = alice.change([_mark("addMark", 0, 10, "em")])
+    b1, _ = bob.change([_ins(5, "Q"), _mark("addMark", 2, 6, "link", {"url": "t.com"})])
+    return [g, m, d], [a1, a2, b1]
+
+
+def _case_edge_anchors():
+    """Marks anchored on the first and last element and on endOfText, while
+    a concurrent writer inserts at both ends."""
+    base = Doc("base")
+    g = _genesis(base, "edges")
+    m, _ = base.change([_mark("addMark", 1, 3, "em")])
+    alice, bob = _writers(("alice", "bob"), [g, m])
+    a1, _ = alice.change(
+        [
+            _mark("addMark", 0, 5, "strong"),
+            _mark("addMark", 0, 1, "link", {"url": "first.com"}),
+            _mark("addMark", 4, 5, "em"),
+            _mark("addMark", 0, 5, "comment", {"id": "all"}),
+            _mark("removeMark", 0, 1, "strong"),
+            _mark("removeMark", 4, 5, "em"),
+        ]
+    )
+    b1, _ = bob.change([_ins(0, "<"), _ins(6, ">")])
+    b2, _ = bob.change([_mark("addMark", 6, 7, "strong")])
+    return [g, m], [a1, b1, b2]
+
+
+def _case_deep_mark_batch():
+    """More than 64 mark ops in one batch: 2M > 128 write nodes take the
+    node tables' gather expansion instead of the select chains."""
+    import random
+
+    rng = random.Random(24)
+    base = Doc("base")
+    g = _genesis(base, "".join(chr(65 + i % 26) for i in range(100)))
+    m, _ = base.change([_mark("addMark", 20, 70, "em")])
+    (w,) = _writers(("w",), [g, m])
+    changes = []
+    for i in range(70):
+        a = rng.randrange(0, 95)
+        end = min(a + 1 + rng.randrange(30), 100)
+        mt = rng.choice(["strong", "em", "link"])
+        add = mt == "link" or bool(i % 3)
+        ops = [
+            _mark(
+                "addMark" if add else "removeMark", a, end, mt,
+                {"url": f"u{i}.com"} if mt == "link" else None,
             )
-        )(batch["states"], text, ro, mark_ops, buf)
+        ]
+        if i % 10 == 0:
+            ops.append(_ins(rng.randrange(0, 90), "ij"))
+        ch, _ = w.change(ops)
+        changes.append(ch)
+    return [g, m], changes
 
-    ref = run()  # module default (sort)
-    for mode in ("scatter", "roll"):
-        monkeypatch.setattr(K, "_SPLICE_MODE", mode)
-        out = run()
-        assert_states_equal(ref, out, f"{mode} vs default splice")
+
+_DIFF_CASES = {
+    "concurrent_marks": (_case_concurrent_marks, {}),
+    "chained_rounds": (_case_chained_rounds, {"min_rounds": 2}),
+    "genesis": (_case_genesis, {}),
+    "tombstones": (_case_tombstones, {}),
+    "edge_anchors": (_case_edge_anchors, {}),
+    "deep_mark_batch": (_case_deep_mark_batch, {"min_marks": 65}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DIFF_CASES))
+def test_sorted_merge_matches_scan_on_edge_cases(case):
+    """Field by field on every DocState plane, the sorted merge (placement
+    by shift passes with the boundary tables riding them, then the batched
+    mark phase) equals the sequential scan merge on targeted batches."""
+    build, expect = _DIFF_CASES[case]
+    base_changes, batch_changes = build()
+    actors, attrs = ActorRegistry(), AttrRegistry()
+    text_obj = None
+    base_rows = np.zeros((0, K.OP_FIELDS), np.int32)
+    if base_changes:
+        base_rows, _, counts = encode_changes(base_changes, actors, attrs)
+        text_obj = counts["text_obj"]
+    rows, _, _ = encode_changes(batch_changes, actors, attrs, text_obj=text_obj)
+    text_rows, mark_rows = split_rows(rows)
+    assert mark_rows.shape[0] >= expect.get("min_marks", 1)
+    ranks_np = np.zeros(64, np.int32)
+    rk = actors.ranks()
+    ranks_np[: len(rk)] = rk
+    ranks = jnp.asarray(ranks_np)
+
+    state = make_empty_state(256, 128)
+    if base_rows.shape[0]:
+        state = K.apply_ops_jit(state, jnp.asarray(base_rows), ranks)
+    states = stack_states([state])
+    marks = jnp.asarray(mark_rows[None, ...])
+
+    ref = K.merge_step_batch(states, jnp.asarray(text_rows[None, ...]), marks, ranks)
+    text, ro, nr, buf, maxk = sorted_inputs([text_rows])
+    assert nr >= expect.get("min_rounds", 1)
+    out = K.merge_step_sorted_batch(states, text, ro, nr, marks, ranks, buf, maxk)
+    assert_states_equal(ref, out, case)
+    assert int(np.asarray(out.mark_count)[0]) > 0
 
 
 def test_mark_window_clamps_at_table_end():

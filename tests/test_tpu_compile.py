@@ -131,6 +131,39 @@ def test_sorted_merge_compiles_at_bench_shape(one_chip):
     _fits_one_chip(_compile(K.merge_step_sorted_batch, args, {}, 1024, one_chip))
 
 
+def test_full_table_merge_has_no_full_plane_gather(one_chip):
+    """The benchmark fleet's full-table merge: 5,120 1k-char docs at
+    capacity 2048 with a 256-op mark table, two 64-op concurrent streams
+    with 8 marks each per replica.  XLA on the TPU runs a gather with
+    per-element indices close to one element at a time, so the merge moves
+    and looks up per-slot data by shift and select passes: no gather in the
+    optimised program may produce R * C elements or more."""
+    import re
+
+    from peritext_tpu.ops.state import make_empty_state, stack_states
+
+    replicas, capacity = 5120, 2048
+    states = stack_states([make_empty_state(capacity, 256)] * SMALL_R)
+    args = (
+        states,
+        np.zeros((SMALL_R, 128, K.OP_FIELDS), np.int32),  # text rows
+        np.zeros((SMALL_R, 128), np.int32),  # placement round of each row
+        np.array(1, np.int32),  # num_rounds: a runtime scalar, as launched
+        np.zeros((SMALL_R, 16, K.OP_FIELDS), np.int32),  # mark rows
+        np.zeros(64, np.int32),  # actor ranks
+        np.zeros((SMALL_R, 128), np.int32),  # run chars
+        4,  # maxk
+    )
+    compiled = _compile(K.merge_step_sorted_batch, args, {}, replicas, one_chip)
+    _fits_one_chip(compiled)
+    big = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* gather\(", line)
+        if m and np.prod([int(d) for d in m.group(2).split(",") if d]) >= replicas * capacity:
+            big.append(m.group(1))
+    assert not big, f"full-plane gathers in the merge: {big}"
+
+
 def test_smoke_fleet_fits_one_chip(one_chip, monkeypatch):
     """chip_smoke's fleet width on one chip: the genesis merge (the
     phase's largest program) must fit the chip's HBM."""
