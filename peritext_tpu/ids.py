@@ -12,18 +12,16 @@ integer ids by :class:`ActorRegistry`, and comparisons use the actor's
 """
 from __future__ import annotations
 
-import re
 from typing import Dict, List, Tuple
-
-_OPID_RE = re.compile(r"^([0-9]+)@(.*)$", re.DOTALL)
 
 
 def parse_op_id(op_id: str) -> Tuple[int, str]:
-    """Split ``"ctr@actor"`` into ``(ctr, actor)``. Reference micromerge.ts:815-823."""
-    m = _OPID_RE.match(op_id)
-    if m is None:
+    """Split ``"ctr@actor"`` into ``(ctr, actor)``: ASCII digits, then the
+    first ``@``, then the actor (anything).  Reference micromerge.ts:815-823."""
+    ctr, at, actor = op_id.partition("@")
+    if not at or not ctr.isdigit() or not ctr.isascii():
         raise ValueError(f"Invalid operation ID: {op_id}")
-    return int(m.group(1)), m.group(2)
+    return int(ctr), actor
 
 
 def make_op_id(counter: int, actor: str) -> str:

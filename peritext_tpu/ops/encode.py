@@ -62,7 +62,16 @@ def encode_internal_op(
     op: Dict[str, Any], actors: ActorRegistry, attrs: AttrRegistry
 ) -> Optional[np.ndarray]:
     """One wire-format internal op -> an int32 op row, or None for map ops."""
-    row = np.zeros(K.OP_FIELDS, np.int32)
+    row = _op_row(op, actors, attrs)
+    return None if row is None else np.asarray(row, np.int32)
+
+
+def _op_row(
+    op: Dict[str, Any], actors: ActorRegistry, attrs: AttrRegistry
+) -> Optional[List[int]]:
+    """:func:`encode_internal_op` as a list of ints (batches build their
+    row matrix once, not an array per op)."""
+    row = [0] * K.OP_FIELDS
     ctr, actor = parse_op_id(op["opId"])
     row[K.K_CTR] = ctr
     row[K.K_ACT] = actors.intern(actor)
@@ -212,7 +221,7 @@ def encode_changes(
     ``text_obj`` is the replica's established device text-list id (None
     before genesis).
     """
-    rows: List[np.ndarray] = []
+    rows: List[List[int]] = []
     row_pos: List[int] = []
     host_ops: List[Tuple[int, Dict[str, Any]]] = []
     counts: Dict[str, Any] = {"insert": 0, "mark": 0}
@@ -237,7 +246,7 @@ def encode_changes(
                     text_obj = op["opId"]
                 host_ops.append((pos, op))
             else:
-                row = encode_internal_op(op, actors, attrs)
+                row = _op_row(op, actors, attrs)
                 if row is None:
                     raise ValueError(
                         f"op {op.get('opId')!r} is a map op targeting the "
@@ -250,10 +259,7 @@ def encode_changes(
                 rows.append(row)
                 row_pos.append(pos)
             pos += 1
-    if rows:
-        out = np.stack(rows)
-    else:
-        out = np.zeros((0, K.OP_FIELDS), np.int32)
+    out = np.asarray(rows, np.int32).reshape(len(rows), K.OP_FIELDS)
     counts["row_pos"] = np.asarray(row_pos, np.int64)
     counts["text_obj"] = text_obj
     return out, host_ops, counts

@@ -184,6 +184,41 @@ def _initial_span_cap() -> int:
     return bucket_length(cap, minimum=1)
 
 
+# Once TpuUniverse.warm_patched has compiled a universe's patched
+# programs ahead of traffic, every patched launch keeps to their shapes:
+# the run-length cap pads to at least WARM_RUN (the first rung of encode's
+# row bucket, which op and mark rows already pad to) and the char buffer to
+# hold one char past its MAX_RUN_LEN tail; the marked and allowMultiple
+# variants compile whatever the batch holds, with the compact readback's
+# candidates sized by the mark table; windows are at least
+# 1/WARM_WINDOW_SHARE of the table (so the full-table merge and at most
+# three window widths at any capacity); and no winner cache persists.
+WARM_RUN = bucket_length(1)
+WARM_BUF = bucket_length(K.MAX_RUN_LEN + 1)
+WARM_WINDOW_SHARE = 8
+
+
+def _warm_narrowest(capacity: int) -> int:
+    """The narrowest window a warmed universe's census picks."""
+    return bucket_length(capacity // WARM_WINDOW_SHARE, minimum=W.MIN_WINDOW)
+
+
+def _warm_windows(capacity: int) -> List[int]:
+    """Every window width a warmed universe's census can pick at
+    ``capacity`` (pow2 from :func:`_warm_narrowest` up to half the table);
+    empty where the windowed merge does not engage."""
+    if not _window_enabled() or capacity < _window_min_cap():
+        return []
+    if os.environ.get("PERITEXT_SORTED_CHUNK") or os.environ.get("PERITEXT_PATCH_CHUNK"):
+        return []
+    caps = []
+    w = _warm_narrowest(capacity)
+    while 2 * w <= capacity:
+        caps.append(w)
+        w *= 2
+    return caps
+
+
 def _codepoints_to_str(codepoints: np.ndarray) -> str:
     """Vectorized codepoint-array -> str (no per-char Python loop).
 
@@ -808,6 +843,12 @@ class TpuUniverse:
         # registry or its padded width changes — interning renumbers
         # ranks, and both events change the key).
         self._ranks_cache: Optional[List[Any]] = None
+        # Keys of the programs the last apply_changes_with_patches
+        # launched, and of those warm_patched ran ahead of traffic; once
+        # warmed, patched launches keep to the warmed shapes (WARM_RUN).
+        self.launch_keys: List[tuple] = []
+        self.warmed_keys: set = set()
+        self._warmed = False
         # Lightweight observability counters (the reference's observability
         # is console logging + the demo op panel, SURVEY §5; at batch scale
         # these are what perf debugging needs).
@@ -1464,6 +1505,9 @@ class TpuUniverse:
             plan = W.plan_windows(
                 mirrors, rows_of, ins_of, ranks, self.capacity,
                 _window_min_cap(), census_keys=keys,
+                min_width=(
+                    _warm_narrowest(self.capacity) if self._warmed else W.MIN_WINDOW
+                ),
             )
         if plan is None:
             self._window_reject_streak += 1
@@ -2134,7 +2178,11 @@ class TpuUniverse:
         split one continuous-batched launch's stream back into exact
         per-submission patch lists.  The pair list is the same stream in
         the same order; stripping positions yields the default return.
+
+        The key of every program launched lands in ``launch_keys``; after
+        :meth:`warm_patched` the launch keeps to the warmed shapes.
         """
+        self.launch_keys = []
         batches = self._normalize_batches(per_replica)
         prep = self._prepare(batches)
         groups, group_of = prep["groups"], prep["group_of"]
@@ -2265,6 +2313,7 @@ class TpuUniverse:
                 for i in range(0, n, chunk):
                     sl = slice(i, min(i + chunk, n))
                     faults.fire("device_launch")
+                    self.launch_keys.append(("scan", rb, sl.stop - sl.start, ops.shape))
                     st, records = K.apply_ops_patched_batch(
                         jax.tree.map(lambda x: x[sl], self.states),
                         d_ops[sl],
@@ -2345,6 +2394,215 @@ class TpuUniverse:
                 out[name] = _strip_pos(merged, with_positions)
         return out
 
+    def _stage_sorted(
+        self, sorted_prep, mark_rows_list, mark_pos_list, group_of
+    ) -> Dict[str, Any]:
+        """Second half of the patched path's ``ingest.stage``: pad the
+        mark rows (and, once warmed, the char buffer to ``WARM_BUF``) and
+        gather every per-group tensor to its replicas."""
+        mark_pad = bucket_length(
+            max(max((m.shape[0] for m in mark_rows_list), default=1), 1)
+        )
+        bufs = sorted_prep["bufs"]
+        if self._warmed and bufs.shape[1] < WARM_BUF:
+            bufs = np.pad(bufs, ((0, 0), (0, WARM_BUF - bufs.shape[1])))
+        g_mark = np.stack([pad_rows(m, mark_pad) for m in mark_rows_list])
+        g_mark_pos = np.stack(
+            [
+                np.pad(
+                    p.astype(np.int64),
+                    (0, mark_pad - p.shape[0]),
+                    constant_values=TIME_PAD,
+                )
+                for p in mark_pos_list
+            ]
+        ).astype(np.int32)
+        return {
+            "g_mark": g_mark,
+            "g_mark_pos": g_mark_pos,
+            "text": sorted_prep["text"][group_of],
+            "rounds": sorted_prep["rounds"][group_of],
+            "bufs": bufs[group_of],
+            "text_pos": sorted_prep["text_pos"][group_of],
+            "mark": g_mark[group_of],
+            "mark_pos": g_mark_pos[group_of],
+        }
+
+    def _sorted_attempt(
+        self, d_ops, wdev, statics, wc, rb: str, keys: List[tuple],
+        windowed: bool = False,
+    ):
+        """One launch attempt of the patch-emitting sorted merge over the
+        device op tensors ``d_ops`` (text, rounds, bufs, text_pos, mark,
+        mark_pos) — windowed over the device window plan ``wdev`` (starts,
+        hulls, vis_base, vis_after, then the static width), else
+        full-table in R-chunks.  Each launch appends its program's key to
+        ``keys`` (``launch_keys`` / ``warmed_keys``)."""
+        d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos = d_ops
+        n = len(self.replica_ids)
+        chunk = self._patch_chunk(n)
+        ranks = self._ranks_jax()
+        multi = _multi_jax(self._rep_sharding)
+        kw = dict(statics)
+        num_rounds = kw.pop("num_rounds")
+        maxk = kw.pop("maxk")
+        key = (
+            rb, wc is not None, maxk, tuple(sorted(kw.items())),
+            tuple(a.shape for a in d_ops), ranks.shape,
+            (self.capacity, self.max_mark_ops),
+        )
+
+        def attempt():
+            if windowed:
+                # Frontier-bounded window merge: one launch over the
+                # gathered [R, w_cap] windows (never chunked — a window
+                # plan is only produced with the chunk valves unset).
+                d_wstart, d_whull, d_wvb, d_wva, w_cap = wdev
+                faults.fire("device_launch")
+                keys.append(("windowed", w_cap, n) + key)
+                st, records = K.merge_step_sorted_patched_windowed_batch(
+                    self.states, d_wstart, d_whull, d_wvb, d_wva,
+                    d_text, d_rounds, num_rounds, d_mark, ranks, d_bufs, multi,
+                    d_tpos, d_mpos, maxk, w_cap,
+                    wcache_in=wc, readback=rb, **kw,
+                )
+                wcache = records.pop("wcache", None)
+                faults.fire("device_readback")
+                with telemetry.span("ingest.readback", readback=rb, windowed=1):
+                    if telemetry.enabled:
+                        telemetry.flow_steps(readback=rb)
+                    # One batched D2H transfer for all record planes.
+                    rec_np = jax.device_get(records)
+                return (st, [rec_np], wcache), st.length
+            state_slices = []
+            record_chunks: List[Dict[str, np.ndarray]] = []
+            wcache_slices = []
+            for i in range(0, n, chunk):
+                sl = slice(i, min(i + chunk, n))
+                faults.fire("device_launch")
+                keys.append(("full", sl.stop - sl.start) + key)
+                st, records = K.merge_step_sorted_patched_batch(
+                    jax.tree.map(lambda x: x[sl], self.states),
+                    d_text[sl], d_rounds[sl], num_rounds, d_mark[sl], ranks,
+                    d_bufs[sl], multi, d_tpos[sl], d_mpos[sl], maxk,
+                    wcache_in=None if wc is None else wc[sl], readback=rb, **kw,
+                )
+                state_slices.append(st)
+                # Keep the cache on device — reading it back would cost
+                # more than the init it saves.
+                wcache_slices.append(records.pop("wcache", None))
+                faults.fire("device_readback")
+                # The device_get barrier IS the record D2H transfer —
+                # span it here so the critical-path report attributes
+                # readback time separately from device dispatch.
+                with telemetry.span("ingest.readback", readback=rb, chunk=i):
+                    if telemetry.enabled:
+                        telemetry.flow_steps(readback=rb)
+                    record_chunks.append(jax.device_get(records))
+            states = (
+                state_slices[0]
+                if len(state_slices) == 1
+                else jax.tree.map(lambda *xs: jax.numpy.concatenate(xs), *state_slices)
+            )
+            if all(w is not None for w in wcache_slices):
+                wcache = (
+                    wcache_slices[0]
+                    if len(wcache_slices) == 1
+                    else jax.numpy.concatenate(wcache_slices)
+                )
+            else:
+                # Cacheless mark-free launch: rows unchanged but slots
+                # re-permuted, so a stale cache must not survive.
+                wcache = None
+            return (states, record_chunks, wcache), states.length
+
+        return attempt
+
+    def _sorted_statics(
+        self, sorted_prep, has_marks: bool, multi_need: int, cand_cap: int
+    ) -> Dict[str, Any]:
+        """The patched sorted merge's static specializations (plus the
+        traced round count) for one batch; once warmed, the warmed ones."""
+        warmed = self._warmed
+        return {
+            "num_rounds": sorted_prep["num_rounds"],
+            "maxk": max(sorted_prep["maxk"], WARM_RUN) if warmed else sorted_prep["maxk"],
+            "has_marks": has_marks or warmed,
+            "mode": (
+                "dense"
+                if os.environ.get("PERITEXT_PATCH_PATH") == "dense"
+                else "delta"
+            ),
+            "group_k": bucket_length(multi_need, minimum=1),
+            "has_multi": multi_need > 0 or warmed,
+            # The delta scan's carried batch-winner table only needs the
+            # LIVE mark-type registry (pow2-bucketed, like group_k): valid
+            # ops' type ids are < NUM_MARK_TYPES, and the cache plane's
+            # padding types (MAX_MARK_TYPES) pass through its final
+            # compose untouched.
+            "t_act": min(
+                bucket_length(schema.NUM_MARK_TYPES, minimum=1),
+                schema.MAX_MARK_TYPES,
+            ),
+            "span_cap": self._span_cap,
+            # Warmed: every defined slot a replica can hold.
+            "cand_cap": (
+                max(cand_cap, bucket_length(2 * self.max_mark_ops + 2))
+                if warmed
+                else cand_cap
+            ),
+        }
+
+    def _current_wcache(self, multi) -> Any:
+        """The persisted winner cache when it matches the current shapes
+        AND the actor registry it was built under (interning a new actor
+        renumbers every rank the cache stores), else None."""
+        wc = self._wcaches
+        if wc is not None and (
+            self._wcaches_actors != len(self.actors.actors)
+            or wc.shape
+            != (len(self.replica_ids), 2 * self.capacity, int(np.asarray(multi).shape[0]), 4)
+        ):
+            wc = None
+        return wc
+
+    def warm_patched(self) -> int:
+        """Compile, on this universe's device(s), every program a patched
+        sorted launch takes from now on for a batch within the warmed
+        shapes (``WARM_RUN``): the full-table merge and the windowed merge
+        at every width the census can then pick.  Each runs once over
+        all-pad op rows against the committed state — nothing commits —
+        and its key joins ``warmed_keys``, which a launch outside the set
+        misses (the serving plane counts those as ``serve.cold_shapes``).
+        The persisted winner cache is dropped and no longer kept: a
+        windowed launch would gather and scatter the whole ``[R, 2C, T,
+        4]`` cache to splice its window back.  Warm after loading the
+        documents: a grown capacity, mark table, span cap or actor table
+        changes the shapes.  Returns the number of programs run."""
+        self._warmed = True
+        self._wcaches = None
+        n = len(self.replica_ids)
+        none = [np.zeros((0, K.OP_FIELDS), np.int32)]
+        sorted_prep = prepare_sorted_batch(none, max_run=0, pos_list=[np.zeros(0, np.int64)])
+        staged = self._stage_sorted(
+            sorted_prep, none, [np.zeros(0, np.int64)], np.zeros(n, np.int32)
+        )
+        statics = self._sorted_statics(sorted_prep, False, 0, self._cand_cap(
+            {"new_mark_counts": np.asarray(self.mark_counts)}))
+        d_ops = self._put(tuple(staged[k] for k in (
+            "text", "rounds", "bufs", "text_pos", "mark", "mark_pos")))
+        rb = _patch_readback()
+        keys: List[tuple] = []
+        runs = [(None, False)]
+        zero = np.zeros(n, np.int32)
+        for w_cap in _warm_windows(self.capacity):
+            runs.append((self._put((zero, zero, zero, zero)) + (w_cap,), True))
+        for wdev, windowed in runs:
+            out = self._sorted_attempt(d_ops, wdev, statics, None, rb, keys, windowed)()
+            jax.block_until_ready(out[0][0])
+        self.warmed_keys.update(keys)
+        return len(keys)
+
     def _patched_sorted(
         self,
         prep,
@@ -2373,46 +2631,18 @@ class TpuUniverse:
         largest targeted allowMultiple group, already gated under
         PATCH_GROUP_K by the caller) statically sizes the delta scan's
         group resolution — a batch with no multi ops compiles without the
-        per-step group machinery entirely."""
+        per-step group machinery entirely, unless the universe is warmed
+        (:meth:`warm_patched`)."""
         groups, group_of = prep["groups"], prep["group_of"]
-        mode = (
-            "dense"
-            if os.environ.get("PERITEXT_PATCH_PATH") == "dense"
-            else "delta"
-        )
-        has_multi = multi_need > 0
-        group_k = bucket_length(multi_need, minimum=1)
-        # The delta scan's carried batch-winner table only needs the LIVE
-        # mark-type registry (pow2-bucketed, like group_k): valid ops'
-        # type ids are < NUM_MARK_TYPES, and the cache plane's padding
-        # types (MAX_MARK_TYPES) pass through its final compose untouched.
-        t_act = min(
-            bucket_length(schema.NUM_MARK_TYPES, minimum=1),
-            schema.MAX_MARK_TYPES,
-        )
 
         with telemetry.span("ingest.stage"):
-            mark_pad = bucket_length(
-                max(max((m.shape[0] for m in mark_rows_list), default=1), 1)
+            staged = self._stage_sorted(
+                sorted_prep, mark_rows_list, mark_pos_list, group_of
             )
-            g_mark = np.stack([pad_rows(m, mark_pad) for m in mark_rows_list])
-            g_mark_pos = np.stack(
-                [
-                    np.pad(
-                        p.astype(np.int64),
-                        (0, mark_pad - p.shape[0]),
-                        constant_values=TIME_PAD,
-                    )
-                    for p in mark_pos_list
-                ]
-            ).astype(np.int32)
-
-            text_ops = sorted_prep["text"][group_of]
-            rounds = sorted_prep["rounds"][group_of]
-            bufs = sorted_prep["bufs"][group_of]
-            text_pos = sorted_prep["text_pos"][group_of]
-            mark_ops = g_mark[group_of]
-            mark_pos = g_mark_pos[group_of]
+        g_mark, g_mark_pos = staged["g_mark"], staged["g_mark_pos"]
+        host_ops = tuple(
+            staged[k] for k in ("text", "rounds", "bufs", "text_pos", "mark", "mark_pos")
+        )
         wplan = self._window_plan(prep)
         # ONE batched host->device transfer for the whole launch's op
         # tensors (a device_put per array cost ~0.1ms fixed overhead each
@@ -2420,23 +2650,16 @@ class TpuUniverse:
         # dominant term); retries and overflow relaunches reuse the same
         # device arrays.
         with telemetry.span("ingest.put"):
-            ranks = self._ranks_jax()
+            self._ranks_jax()  # the rank table's upload, when it changed
             multi = _multi_jax(self._rep_sharding)
             if wplan is not None:
-                (
-                    d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos,
-                    d_wstart, d_whull, d_wvb, d_wva,
-                ) = self._put(
-                    (
-                        text_ops, rounds, bufs, text_pos, mark_ops, mark_pos,
-                        wplan["starts"], wplan["hulls"], wplan["vis_base"],
-                        wplan["vis_after"],
-                    )
+                put = self._put(
+                    host_ops
+                    + (wplan["starts"], wplan["hulls"], wplan["vis_base"], wplan["vis_after"])
                 )
+                d_ops, wdev = put[:6], tuple(put[6:]) + (wplan["w_cap"],)
             else:
-                d_text, d_rounds, d_bufs, d_tpos, d_mark, d_mpos = self._put(
-                    (text_ops, rounds, bufs, text_pos, mark_ops, mark_pos)
-                )
+                d_ops, wdev = self._put(host_ops), None
         pad_per_group = (sorted_prep["text"][:, :, K.K_KIND] == K.KIND_PAD).sum(
             axis=1
         ) + (g_mark[:, :, K.K_KIND] == K.KIND_PAD).sum(axis=1)
@@ -2448,121 +2671,19 @@ class TpuUniverse:
         # rows anywhere) compiles without the winner-cache init or the
         # mark scan.
         has_marks = any(m.shape[0] for m in mark_rows_list)
-        # Thread the persisted winner cache when it matches the current
-        # shapes AND the actor registry it was built under (interning a
-        # new actor renumbers every rank the cache stores).
-        wc = self._wcaches
-        if wc is not None and (
-            self._wcaches_actors != len(self.actors.actors)
-            or wc.shape
-            != (n, 2 * self.capacity, int(np.asarray(multi).shape[0]), 4)
-        ):
-            wc = None
-
+        statics = self._sorted_statics(
+            sorted_prep, has_marks, multi_need, self._cand_cap(prep)
+        )
+        mode = statics["mode"]
+        # Thread the persisted winner cache when it is current.
+        wc = None if self._warmed else self._current_wcache(multi)
         readback = _patch_readback()
-        span_cap = self._span_cap
-        cand_cap = self._cand_cap(prep)
+        span_cap = statics["span_cap"]
 
         def make_attempt(rb: str, windowed: bool = False):
-            def attempt():
-                if windowed:
-                    # Frontier-bounded window merge: one launch over the
-                    # gathered [R, w_cap] windows (never chunked — a window
-                    # plan is only produced with the chunk valves unset).
-                    faults.fire("device_launch")
-                    st, records = K.merge_step_sorted_patched_windowed_batch(
-                        self.states,
-                        d_wstart,
-                        d_whull,
-                        d_wvb,
-                        d_wva,
-                        d_text,
-                        d_rounds,
-                        sorted_prep["num_rounds"],
-                        d_mark,
-                        ranks,
-                        d_bufs,
-                        multi,
-                        d_tpos,
-                        d_mpos,
-                        sorted_prep["maxk"],
-                        wplan["w_cap"],
-                        has_marks=has_marks,
-                        wcache_in=wc,
-                        mode=mode,
-                        group_k=group_k,
-                        has_multi=has_multi,
-                        t_act=t_act,
-                        readback=rb,
-                        span_cap=span_cap,
-                        cand_cap=cand_cap,
-                    )
-                    wcache = records.pop("wcache", None)
-                    faults.fire("device_readback")
-                    with telemetry.span("ingest.readback", readback=rb, windowed=1):
-                        if telemetry.enabled:
-                            telemetry.flow_steps(readback=rb)
-                        # One batched D2H transfer for all record planes.
-                        rec_np = jax.device_get(records)
-                    return (st, [rec_np], wcache), st.length
-                state_slices = []
-                record_chunks: List[Dict[str, np.ndarray]] = []
-                wcache_slices = []
-                for i in range(0, n, chunk):
-                    sl = slice(i, min(i + chunk, n))
-                    faults.fire("device_launch")
-                    st, records = K.merge_step_sorted_patched_batch(
-                        jax.tree.map(lambda x: x[sl], self.states),
-                        d_text[sl],
-                        d_rounds[sl],
-                        sorted_prep["num_rounds"],
-                        d_mark[sl],
-                        ranks,
-                        d_bufs[sl],
-                        multi,
-                        d_tpos[sl],
-                        d_mpos[sl],
-                        sorted_prep["maxk"],
-                        has_marks=has_marks,
-                        wcache_in=None if wc is None else wc[sl],
-                        mode=mode,
-                        group_k=group_k,
-                        has_multi=has_multi,
-                        t_act=t_act,
-                        readback=rb,
-                        span_cap=span_cap,
-                        cand_cap=cand_cap,
-                    )
-                    state_slices.append(st)
-                    # Keep the cache on device — reading it back would cost
-                    # more than the init it saves.
-                    wcache_slices.append(records.pop("wcache", None))
-                    faults.fire("device_readback")
-                    # The device_get barrier IS the record D2H transfer —
-                    # span it here so the critical-path report attributes
-                    # readback time separately from device dispatch.
-                    with telemetry.span("ingest.readback", readback=rb, chunk=i):
-                        if telemetry.enabled:
-                            telemetry.flow_steps(readback=rb)
-                        record_chunks.append(jax.device_get(records))
-                states = (
-                    state_slices[0]
-                    if len(state_slices) == 1
-                    else jax.tree.map(lambda *xs: jax.numpy.concatenate(xs), *state_slices)
-                )
-                if all(w is not None for w in wcache_slices):
-                    wcache = (
-                        wcache_slices[0]
-                        if len(wcache_slices) == 1
-                        else jax.numpy.concatenate(wcache_slices)
-                    )
-                else:
-                    # Cacheless mark-free launch: rows unchanged but slots
-                    # re-permuted, so a stale cache must not survive.
-                    wcache = None
-                return (states, record_chunks, wcache), states.length
-
-            return attempt
+            return self._sorted_attempt(
+                d_ops, wdev, statics, wc, rb, self.launch_keys, windowed
+            )
 
         use_window = wplan is not None
         try:
@@ -2632,15 +2753,7 @@ class TpuUniverse:
                 telemetry.flow_steps(path="windowed", window=int(wplan["w_cap"]))
             telemetry.counter("ingest.readback." + readback)
             telemetry.counter(
-                "ingest.h2d_bytes",
-                int(
-                    text_ops.nbytes
-                    + mark_ops.nbytes
-                    + bufs.nbytes
-                    + rounds.nbytes
-                    + text_pos.nbytes
-                    + mark_pos.nbytes
-                ),
+                "ingest.h2d_bytes", int(sum(a.nbytes for a in host_ops))
             )
             telemetry.counter("ingest.d2h_bytes", int(d2h))
             # Record-readback accounting (the span covering the actual
@@ -2648,8 +2761,8 @@ class TpuUniverse:
             telemetry.record(
                 "ingest.readback", fmt=readback, d2h_bytes=int(d2h)
             )
-        self._wcaches = wcache
-        if wcache is not None:
+        self._wcaches = None if self._warmed else wcache
+        if self._wcaches is not None:
             # ranks() used by this launch reflect the post-_prepare
             # registry; key the cache to it.
             self._wcaches_actors = len(self.actors.actors)

@@ -31,6 +31,10 @@ from peritext_tpu.ops.encode import bucket_length
 Mirror = Dict[str, np.ndarray]  # keys: ctr, act, deleted, bnd_def
 
 
+# The narrowest window plan_windows picks (its pow2 ladder's first rung).
+MIN_WINDOW = 64
+
+
 def make_mirror(
     ctr: np.ndarray, act: np.ndarray, deleted: np.ndarray, bnd_def: np.ndarray
 ) -> Mirror:
@@ -262,8 +266,10 @@ def plan_windows(
     capacity: int,
     min_cap: int,
     census_keys: Optional[List[Any]] = None,
+    min_width: int = MIN_WINDOW,
 ) -> Optional[Dict[str, Any]]:
-    """Fleet window plan: per-replica hulls + one shared pow2 ``w_cap``.
+    """Fleet window plan: per-replica hulls + one shared pow2 ``w_cap`` of
+    at least ``min_width`` slots.
 
     Returns None (full-table path) when any replica's census fails, when
     the bucketed window would cover more than half the table (no win), or
@@ -299,7 +305,7 @@ def plan_windows(
 
     hulls = [hi - lo + 1 for lo, hi in lo_hi]
     needs = [h + int(inserts_of[r]) for r, h in enumerate(hulls)]
-    w_cap = bucket_length(max(max(needs), 1), minimum=64)
+    w_cap = bucket_length(max(max(needs), 1), minimum=min_width)
     los = [lo for lo, _ in lo_hi]
     # Clamp so the dynamic-slice gather stays in range (start + w_cap <= C);
     # widening leftward is always sound.  Growing w_cap loosens the clamp,
@@ -312,7 +318,7 @@ def plan_windows(
             los[r] = lo_c
             hulls[r] = hi - lo_c + 1 if hi >= lo_c else 0
             needs[r] = hulls[r] + int(inserts_of[r])
-        new_cap = bucket_length(max(max(needs), 1), minimum=64)
+        new_cap = bucket_length(max(max(needs), 1), minimum=min_width)
         if new_cap == w_cap:
             break
         w_cap = new_cap

@@ -59,9 +59,15 @@ The pieces:
   changes).
 - **Shape bucketing**: the batch target is pow2 and the underlying encode
   paths pad rows to pow2 buckets, so steady-state cohorts reuse a handful
-  of compiled programs; the plane tracks the (replicas, capacity,
-  ops-bucket, marks-bucket) shape key per flush as
-  ``serve.compile_cache_{hit,miss}``.
+  of compiled programs.  :meth:`ServePlane.warm` compiles, on the plane's
+  device before traffic, every program a cohort of short changes can
+  launch (``TpuUniverse.warm_patched``: from then on the launches keep to
+  the warmed shapes), in a ``serve.warm`` span; a launch outside that set
+  counts ``serve.cold_shapes``.  The plane also tracks the
+  (replicas, capacity, ops-bucket, marks-bucket) shape key per flush as
+  ``serve.compile_cache_{hit,miss}``, and observes the launches in flight
+  across the plane (its shards included) at each launch as
+  ``serve.launches_in_flight``.
 
 Disabled-telemetry contract: every serve site guards on the single
 ``telemetry.enabled`` attribute (one attr check, no call, no allocation —
@@ -142,12 +148,37 @@ def _bucket_pow2(n: int) -> int:
     return out
 
 
+# The first rung of encode's row bucket (``ops.encode.bucket_length``):
+# op and mark rows never pad to fewer.
+ROW_BUCKET = 8
+
+
+class LaunchGauge:
+    """Launches in flight across one serving plane — every shard of a
+    sharded plane shares one gauge.  ``enter`` returns the count with the
+    caller's launch included."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._n = 0
+
+    def enter(self) -> int:
+        with self._lock:
+            self._n += 1
+            return self._n
+
+    def exit(self) -> None:
+        with self._lock:
+            self._n -= 1
+
+
 def cohort_shape_key(universe: Any, per_replica: Dict[str, List[Change]]) -> tuple:
     """The jit-cache shape proxy for one cohort: replica count and device
-    capacity plus pow2 buckets of the widest per-replica op and mark-row
-    counts — the axes that dominate the compiled program shape (encode
-    pads rows to these buckets).  THE one definition, shared by the
-    plane's ``serve.compile_cache_{hit,miss}`` tracking and the serve A/B
+    capacity plus pow2 buckets (from ``ROW_BUCKET``) of the widest
+    per-replica op and mark-row counts — the axes that dominate the
+    compiled program shape (encode pads rows to these buckets).  THE one
+    definition, shared by the plane's ``serve.compile_cache_{hit,miss}``
+    tracking and the serve A/B
     harness's naive-leg shape count, so the two sides always compare the
     same key."""
     max_ops = 0
@@ -165,8 +196,8 @@ def cohort_shape_key(universe: Any, per_replica: Dict[str, List[Change]]) -> tup
     return (
         len(universe.replica_ids),
         universe.capacity,
-        _bucket_pow2(max(1, max_ops)),
-        _bucket_pow2(max(1, max_marks)),
+        _bucket_pow2(max(ROW_BUCKET, max_ops)),
+        _bucket_pow2(max(ROW_BUCKET, max_marks)),
     )
 
 
@@ -351,9 +382,13 @@ class ServePlane:
         start: bool = True,
         name: str = "serve",
         shard: Optional[int] = None,
+        gauge: Optional[LaunchGauge] = None,
     ) -> None:
         self._uni = universe
         self.name = name
+        # Launches in flight across the plane: a ShardedServePlane hands
+        # every shard its one gauge.
+        self._gauge = gauge if gauge is not None else LaunchGauge()
         # Shard id when this plane is one slice of a ShardedServePlane
         # (runtime/serve_shard.py): stamps the serve.submit causal lanes
         # and the serve.flush spans, and keys the per-shard
@@ -410,6 +445,8 @@ class ServePlane:
             "compile_cache_hits": 0,
             "compile_cache_misses": 0,
             "flush_failures": 0,
+            "warmed_programs": 0,
+            "cold_shapes": 0,
         }
         self._thread: Optional[threading.Thread] = None
         # Live ops surface (ISSUE 13): the plane contributes per-session
@@ -859,6 +896,7 @@ class ServePlane:
         with telemetry.span("serve.flush", **span_meta):
             for ctx in ctxs:
                 telemetry.flow_point(ctx)
+            in_flight = self._gauge.enter()
             with telemetry.flowing(ctxs):
                 try:
                     out = self._uni.apply_changes_with_patches(
@@ -866,7 +904,12 @@ class ServePlane:
                     )
                 except BaseException as exc:
                     err = exc
+                finally:
+                    self._gauge.exit()
             flush_s = time.perf_counter() - t0
+            self._count_cold()
+            if telemetry.enabled:
+                telemetry.observe("serve.launches_in_flight", in_flight)
             with telemetry.span("serve.resolve", flush=seq):
                 if err is None:
                     self._resolve_subs(subs, out, seq, flush_s)
@@ -909,6 +952,36 @@ class ServePlane:
             )
         with self._work:
             self._work.notify_all()  # blocked submitters + drain waiters
+
+    def _count_cold(self) -> None:
+        """Tally the last launch's programs that :meth:`warm` did not
+        compile (``serve.cold_shapes``, plane-wide and per shard)."""
+        cold = sum(k not in self._uni.warmed_keys for k in self._uni.launch_keys)
+        if not cold:
+            return
+        with self._lock:
+            self.stats["cold_shapes"] += cold
+        if telemetry.enabled:
+            telemetry.counter("serve.cold_shapes", cold)
+            if self.shard is not None:
+                telemetry.counter(f"serve.shard.{self.shard}.cold_shapes", cold)
+
+    def warm(self) -> int:
+        """Compile, on this plane's device, every program a cohort of short
+        changes can launch (``TpuUniverse.warm_patched``: the full-table
+        merge and every window width; from then on the plane's launches
+        keep to those shapes, and one outside them counts
+        ``serve.cold_shapes``).  Call after the documents are loaded and
+        before traffic; runs between flushes.  Returns the number of
+        programs run."""
+        meta: Dict[str, Any] = {"plane": self.name}
+        if self.shard is not None:
+            meta["shard"] = self.shard
+        with telemetry.span("serve.warm", **meta):
+            n = self.run_quiesced(self._uni.warm_patched)
+        with self._lock:
+            self.stats["warmed_programs"] += n
+        return n
 
     def _resolve_subs(self, subs, out, seq, flush_s: float) -> None:
         """Split each replica's positioned stream into per-submission

@@ -45,8 +45,12 @@ handed (client submits + cross-shard deliveries), because every shard is
 a full ServePlane with the same admission gate.
 
 Manual mode (``start=False``) steps/drains every shard deterministically;
-threaded mode runs one scheduler thread per shard.  Env defaults:
-``PERITEXT_SERVE_SHARDS`` (shard count), ``PERITEXT_SERVE_SHARD_BUCKET``
+threaded mode runs one scheduler thread per shard, and the shards' launches
+share one ``serve.launches_in_flight`` gauge.  :meth:`ShardedServePlane.
+warm` compiles every shard's cohort programs on that shard's device(s),
+the shards concurrently; :meth:`ShardedServePlane.open_sessions` opens
+many sessions at once, each shard's universe built at its final width.
+Env defaults: ``PERITEXT_SERVE_SHARDS`` (shard count), ``PERITEXT_SERVE_SHARD_BUCKET``
 (``pow2`` | ``exact``), ``PERITEXT_SERVE_PLACEMENT`` (``rr`` | ``load`` —
 new sessions round-robin or join the least-loaded shard),
 ``PERITEXT_ELASTIC=1`` (attach the SLO-driven autoscaler,
@@ -63,6 +67,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from peritext_tpu.runtime import telemetry
 from peritext_tpu.runtime.pubsub import Publisher
 from peritext_tpu.runtime.serve import (
+    LaunchGauge,
     ServePlane,
     ServeSession,
     Submission,
@@ -336,6 +341,7 @@ class ShardedServePlane:
             on_open=on_open,
         )
         self._start = start
+        self._gauge = LaunchGauge()
         self._lock = threading.RLock()
         self._devices = devices
         self._slices: Optional[List[List[Any]]] = None
@@ -456,6 +462,19 @@ class ShardedServePlane:
         mesh = make_mesh(shard.devices, len(shard.devices), 1)
         shard.universe.shard(mesh, shard_seq=False)
 
+    def _bring_up(self, shard: _Shard, replica_ids: List[str]) -> None:
+        """Build a shard's universe over ``replica_ids`` and its plane."""
+        shard.universe = self._make_universe(shard, replica_ids)
+        shard.plane = ServePlane(
+            shard.universe,
+            start=self._start,
+            name=f"{self.name}.shard{shard.index}",
+            shard=shard.index,
+            gauge=self._gauge,
+            **self._plane_kw,
+        )
+        self._reshard_slice(shard)
+
     def _provision_locked(self, shard: _Shard, replica: str) -> None:
         """Bring ``replica`` up on ``shard``, holding the universe width
         EXACTLY to the bucket policy: pow2 width = pow2(real sessions),
@@ -473,16 +492,7 @@ class ShardedServePlane:
             else len(shard.real)
         )
         if shard.universe is None:
-            ids = [replica] + self._mint_pads(shard, target - 1)
-            shard.universe = self._make_universe(shard, ids)
-            shard.plane = ServePlane(
-                shard.universe,
-                start=self._start,
-                name=f"{self.name}.shard{shard.index}",
-                shard=shard.index,
-                **self._plane_kw,
-            )
-            self._reshard_slice(shard)
+            self._bring_up(shard, [replica] + self._mint_pads(shard, target - 1))
             return
 
         def mutate() -> None:
@@ -628,6 +638,66 @@ class ShardedServePlane:
                 telemetry.gauge("serve.sessions", len(self._sessions))
                 telemetry.counter(f"serve.shard.{shard}.sessions")
         return sess
+
+    def open_sessions(
+        self, specs: Sequence[Tuple[str, str]], **session_kw: Any
+    ) -> List[ShardSession]:
+        """Open one session per ``(name, replica)`` in ``specs``, placed as
+        that many :meth:`session` calls in turn would place them, with the
+        same result: the same shards, each shard's universe at the same
+        pow2 width with its real replicas in the same order.  A shard that
+        has no universe yet gets it built once at its final width (one
+        state allocation, not a doubling per bucket boundary); any other
+        shard, a ``doc``-grouped session, or an attached lifecycle or
+        autoscaler takes the one-at-a-time path.  ``session_kw`` are
+        :meth:`ServePlane.session`'s."""
+        if self.lifecycle is not None or self.elastic is not None or "doc" in session_kw:
+            return [self.session(n, r, **session_kw) for n, r in specs]
+        with self._lock:
+            names = [n for n, _ in specs]
+            seen_n: set = set(self._sessions)
+            seen_r: set = set(self._by_replica)
+            for n, r in specs:
+                if n in seen_n:
+                    raise ValueError(f"session {n!r} already exists")
+                if r in seen_r:
+                    raise ValueError(f"replica {r!r} is already fronted")
+                seen_n.add(n)
+                seen_r.add(r)
+            placed: Dict[int, List[Tuple[str, str]]] = {}
+            loads = [self._shard_load_locked(s) for s in self.shards]
+            sizes = [len(s.real) for s in self.shards]
+            for spec in specs:
+                if self.placement == PLACEMENT_LOAD:
+                    i = min(range(len(self.shards)), key=lambda j: (loads[j], sizes[j], j))
+                else:
+                    i = self._next_shard
+                    self._next_shard = (self._next_shard + 1) % len(self.shards)
+                loads[i] += 1
+                sizes[i] += 1
+                placed.setdefault(i, []).append(spec)
+            out: Dict[str, ShardSession] = {}
+            for i, group in placed.items():
+                slot = self.shards[i]
+                if slot.universe is None:
+                    slot.real.extend(r for _, r in group)
+                    width = self._bucket_target(slot)
+                    self._bring_up(
+                        slot, slot.real + self._mint_pads(slot, width - len(slot.real))
+                    )
+                else:
+                    for _, r in group:
+                        self._provision_locked(slot, r)
+                for n, r in group:
+                    inner = slot.plane.session(n, r, **session_kw)
+                    sess = out[n] = ShardSession(self, inner, i, None)
+                    self._sessions[n] = sess
+                    self._by_replica[r] = sess
+                if telemetry.enabled:
+                    telemetry.counter(f"serve.shard.{i}.sessions", len(group))
+            if telemetry.enabled:
+                telemetry.gauge("serve.sessions", len(self._sessions))
+        return [out[n] for n in names]
 
     # -- placement + load ----------------------------------------------------
 
@@ -789,6 +859,33 @@ class ShardedServePlane:
         fan-out happens at submit time, never during a flush, so one
         shard's flush can never unblock another's deferred lane."""
         return sum(plane.drain(max_steps) for plane in self._planes())
+
+    def warm(self) -> int:
+        """:meth:`ServePlane.warm` on every shard, each on its own device(s)
+        and all at once (one thread a shard: compiles for different
+        devices are separate programs and run side by side).  Returns the
+        number of programs run across the shards."""
+        planes = self._planes()
+        counts: List[int] = [0] * len(planes)
+        errors: List[BaseException] = []
+
+        def one(k: int) -> None:
+            try:
+                counts[k] = planes[k].warm()
+            except BaseException as exc:  # re-raised on the caller's thread
+                errors.append(exc)
+
+        threads = [
+            threading.Thread(target=one, args=(k,), name=f"peritext-warm-{k}")
+            for k in range(len(planes))
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        if errors:
+            raise errors[0]
+        return sum(counts)
 
     def flush_and_wait(self, timeout: float = 30.0) -> None:
         for plane in self._planes():
