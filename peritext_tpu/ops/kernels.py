@@ -3313,3 +3313,34 @@ def merge_step_sorted_patched_windowed_batch(
     if wcache_in is not None:
         args.append(wcache_in)
     return fn(*args)
+
+
+@jax.jit
+def window_reach_bound(elem_ctr, elem_act, length, ids, head, inserts):
+    """Lower bound on the window census's largest ``hull + inserts``, read
+    from committed state without the mirror (ops/window.py,
+    ``reach_queries`` / ``reach_rejects``).
+
+    ``ids`` [R, K, 2] are (ctr, act) pairs replica r's batch looks up in
+    its table (-1 pads match nothing), ``head`` [R] marks a batch that
+    inserts at position 0, ``inserts`` [R] its insert count.  The census
+    hull holds every id it finds, so it spans at least the smallest to the
+    largest of their positions.  Returns the fleet-wide maximum of that
+    span plus the replica's inserts: one int32."""
+    cap = elem_ctr.shape[-1]
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    live = pos < length[:, None]
+    hit = (
+        live[:, None, :]
+        & (elem_ctr[:, None, :] == ids[:, :, 0:1])
+        & (elem_act[:, None, :] == ids[:, :, 1:2])
+    )
+    # Ids are unique in a table: one reduction per id gives its position
+    # (or -1), and the [R, K, C] compare never leaves the fusion.
+    at = jnp.max(jnp.where(hit, pos, -1), axis=-1)
+    lo = jnp.min(jnp.where(at >= 0, at, cap), axis=-1)
+    hi = jnp.max(at, axis=-1)
+    lo = jnp.where(head, 0, lo)
+    hi = jnp.where(head, jnp.maximum(hi, 0), hi)
+    span = jnp.where(hi >= lo, hi - lo + 1, 0)
+    return jnp.max(span + inserts)

@@ -213,7 +213,7 @@ def _warm_windows(capacity: int) -> List[int]:
         return []
     caps = []
     w = _warm_narrowest(capacity)
-    while 2 * w <= capacity:
+    while W.window_fits(w, capacity):
         caps.append(w)
         w *= 2
     return caps
@@ -1454,6 +1454,38 @@ class TpuUniverse:
             )
         return self._mirror
 
+    def _reach_bound(
+        self, rows: List[np.ndarray], inserts: Sequence[int], group_of: np.ndarray
+    ) -> int:
+        """The device reach bound (kernels.window_reach_bound) of a batch
+        over the committed state: per group, its op rows and insert count;
+        ``group_of`` maps each replica to its group."""
+        ids, head = W.reach_queries(rows)
+        host = (ids[group_of], head[group_of], np.asarray(inserts, np.int32)[group_of])
+        if telemetry.enabled:
+            telemetry.counter("ingest.h2d_bytes", int(sum(a.nbytes for a in host)))
+        s = self.states
+        return int(K.window_reach_bound(s.elem_ctr, s.elem_act, s.length, *self._put(host)))
+
+    def _reach_rejects(self, prep: Dict[str, Any], min_width: int) -> bool:
+        """Whether the device reach bound alone shows the census would
+        reject this batch (every window too wide), so the stale mirror
+        need not be rebuilt to find that out.  A necessary condition only:
+        False leaves the decision to the census."""
+        groups = prep["groups"]
+        bound = self._reach_bound(
+            [g["rows"] for g in groups], [g["inserts"] for g in groups], prep["group_of"]
+        )
+        self.stats["window_reach_checks"] = self.stats.get("window_reach_checks", 0) + 1
+        if telemetry.enabled:
+            telemetry.counter("ingest.window_reach_checks")
+        if not W.reach_rejects(bound, self.capacity, min_width):
+            return False
+        self.stats["window_reach_rejects"] = self.stats.get("window_reach_rejects", 0) + 1
+        if telemetry.enabled:
+            telemetry.counter("ingest.window_reach_rejects")
+        return True
+
     def _window_plan(self, prep: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         """Window plan for a prepared batch, or None for the full path.
 
@@ -1463,7 +1495,9 @@ class TpuUniverse:
         (< PERITEXT_MERGE_WINDOW_MIN) aren't worth the gather/scatter; and
         plan_windows itself falls back when any replica's census cannot
         bound its batch or the bucketed window would cover more than half
-        the table."""
+        the table.  Where the mirror is stale, the device reach bound
+        (:meth:`_reach_rejects`) runs first and rejects, without the
+        rebuild, a batch whose window cannot fit."""
         if not _window_enabled():
             return None
         if os.environ.get("PERITEXT_SORTED_CHUNK") or os.environ.get(
@@ -1484,10 +1518,10 @@ class TpuUniverse:
             self.lengths[r] == 0 and rows_of[r].shape[0] for r in range(n)
         ):
             return None
-        # Backoff after a rejection streak: every census below this point
-        # costs a mirror rebuild (full-table commits invalidated it), so a
+        # Backoff after a rejection streak: a census below this point can
+        # cost a mirror rebuild (full-table commits invalidated it), so a
         # workload whose hulls are persistently too wide would otherwise
-        # pay a fleet-wide D2H per batch with nothing to show for it.
+        # pay a fleet-wide D2H per batch the reach bound cannot rule out.
         if self._window_census_skip > 0:
             self._window_census_skip -= 1
             self.stats["window_census_skips"] = (
@@ -1497,18 +1531,23 @@ class TpuUniverse:
                 telemetry.counter("ingest.window_census_skips")
             return None
         ranks = self._ranks_host()
+        min_width = _warm_narrowest(self.capacity) if self._warmed else W.MIN_WINDOW
         with telemetry.span("ingest.window_census"):
-            mirrors = self._mirrors()
-            keys = [
-                (self._mirror_class[r], int(group_of[r])) for r in range(n)
-            ]
-            plan = W.plan_windows(
-                mirrors, rows_of, ins_of, ranks, self.capacity,
-                _window_min_cap(), census_keys=keys,
-                min_width=(
-                    _warm_narrowest(self.capacity) if self._warmed else W.MIN_WINDOW
-                ),
-            )
+            # A stale mirror costs a fleet-wide readback to rebuild: first
+            # ask the device whether the census could accept at all.
+            if self._mirror_token is not self.states and self._reach_rejects(
+                prep, min_width
+            ):
+                plan = None
+            else:
+                mirrors = self._mirrors()
+                keys = [
+                    (self._mirror_class[r], int(group_of[r])) for r in range(n)
+                ]
+                plan = W.plan_windows(
+                    mirrors, rows_of, ins_of, ranks, self.capacity,
+                    _window_min_cap(), census_keys=keys, min_width=min_width,
+                )
         if plan is None:
             self._window_reject_streak += 1
             threshold = _window_backoff()
@@ -2569,8 +2608,9 @@ class TpuUniverse:
     def warm_patched(self) -> int:
         """Compile, on this universe's device(s), every program a patched
         sorted launch takes from now on for a batch within the warmed
-        shapes (``WARM_RUN``): the full-table merge and the windowed merge
-        at every width the census can then pick.  Each runs once over
+        shapes (``WARM_RUN``): the full-table merge, the windowed merge
+        at every width the census can then pick, and the census's reach
+        bound.  Each runs once over
         all-pad op rows against the committed state — nothing commits —
         and its key joins ``warmed_keys``, which a launch outside the set
         misses (the serving plane counts those as ``serve.cold_shapes``).
@@ -2600,6 +2640,9 @@ class TpuUniverse:
         for wdev, windowed in runs:
             out = self._sorted_attempt(d_ops, wdev, statics, None, rb, keys, windowed)()
             jax.block_until_ready(out[0][0])
+        if len(runs) > 1:  # the census runs, and with it the reach bound
+            self._reach_bound(none, [0], np.zeros(n, np.int32))
+            keys.append(("window_reach", n, self.capacity))
         self.warmed_keys.update(keys)
         return len(keys)
 

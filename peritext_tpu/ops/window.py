@@ -34,6 +34,22 @@ Mirror = Dict[str, np.ndarray]  # keys: ctr, act, deleted, bnd_def
 # The narrowest window plan_windows picks (its pow2 ladder's first rung).
 MIN_WINDOW = 64
 
+# Committed ids per replica the reach bound looks up (reach_queries): a
+# fixed width, so the bound compiles once per fleet shape.
+REACH_IDS = 32
+
+
+def window_cap(need: int, min_width: int) -> int:
+    """The pow2 window width (at least ``min_width``) that holds ``need``
+    element slots."""
+    return bucket_length(max(need, 1), minimum=min_width)
+
+
+def window_fits(w_cap: int, capacity: int) -> bool:
+    """Whether a ``w_cap``-wide window is worth gathering: at most half
+    the table."""
+    return 2 * w_cap <= capacity
+
 
 def make_mirror(
     ctr: np.ndarray, act: np.ndarray, deleted: np.ndarray, bnd_def: np.ndarray
@@ -258,6 +274,69 @@ def replica_window(
     return (min(los), max(his))
 
 
+def reach_queries(
+    rows_of: List[np.ndarray], width: int = REACH_IDS
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The committed ids each batch in ``rows_of`` looks up, for the
+    device reach bound (kernels.window_reach_bound).
+
+    Returns ``ids`` ``[len(rows_of), width, 2]`` int32 of (ctr, act) pairs
+    padded with -1, and ``head`` ``[len(rows_of)]``: the batch inserts at
+    the text's head (position 0).  The ids are insert references, mark
+    anchors (end anchors unless endOfText) and delete targets, in that
+    order, without the ids the batch itself creates and without repeats;
+    past ``width`` the rest are dropped.  replica_window's hull holds the
+    position of every one of them it finds, so any subset bounds the hull
+    from below."""
+    n_g = len(rows_of)
+    ids = np.full((n_g, width, 2), -1, np.int32)
+    head = np.zeros(n_g, bool)
+    sizes = [r.shape[0] for r in rows_of]
+    if not sum(sizes):
+        return ids, head
+    rows = np.concatenate(rows_of)
+    gid = np.repeat(np.arange(n_g), sizes)
+    kind = rows[:, K.K_KIND]
+    ins = kind == K.KIND_INSERT
+    at_head = ins & (rows[:, K.K_REF_CTR] == 0) & (rows[:, K.K_REF_ACT] == 0)
+    head[gid[at_head]] = True
+    mark = kind == K.KIND_MARK
+    parts = [
+        (ins & ~at_head, K.K_REF_CTR, K.K_REF_ACT),
+        (mark, K.K_SCTR, K.K_SACT),
+        (mark & (rows[:, K.K_EKIND] != 2), K.K_ECTR, K.K_EACT),
+        (kind == K.KIND_DELETE, K.K_REF_CTR, K.K_REF_ACT),
+        (ins, K.K_CTR, K.K_ACT),  # created: dropped below
+    ]
+    q_g = np.concatenate([gid[m] for m, _, _ in parts])
+    q_id = np.concatenate([_id_keys(rows[m, c], rows[m, a]) for m, c, a in parts])
+    n_made = int(ins.sum())
+    # One dense key per (group, id): ids interned, then offset by group.
+    uniq, inv = np.unique(q_id, return_inverse=True)
+    key = q_g.astype(np.int64) * uniq.shape[0] + inv.reshape(-1)
+    n_q = key.shape[0] - n_made
+    made = key[n_q:]
+    key, q_g = key[:n_q], q_g[:n_q]
+    _, first = np.unique(key, return_index=True)
+    first = np.sort(first)  # the first of each repeat, in priority order
+    first = first[~np.isin(key[first], made)]
+    order = first[np.argsort(q_g[first], kind="stable")]
+    g = q_g[order]
+    rank = np.arange(order.shape[0]) - np.searchsorted(g, g)
+    keep = rank < width
+    order, g, rank = order[keep], g[keep], rank[keep]
+    ids[g, rank, 0] = (q_id[order] >> 32).astype(np.int32)
+    ids[g, rank, 1] = (q_id[order] & 0xFFFFFFFF).astype(np.int32)
+    return ids, head
+
+
+def reach_rejects(bound: int, capacity: int, min_width: int) -> bool:
+    """Whether a lower bound on the largest replica's ``hull + inserts``
+    already fails plan_windows's width test, so its census can only
+    return None."""
+    return not window_fits(window_cap(bound, min_width), capacity)
+
+
 def plan_windows(
     mirrors: List[Optional[Mirror]],
     rows_of: List[np.ndarray],
@@ -305,20 +384,20 @@ def plan_windows(
 
     hulls = [hi - lo + 1 for lo, hi in lo_hi]
     needs = [h + int(inserts_of[r]) for r, h in enumerate(hulls)]
-    w_cap = bucket_length(max(max(needs), 1), minimum=min_width)
+    w_cap = window_cap(max(needs), min_width)
     los = [lo for lo, _ in lo_hi]
     # Clamp so the dynamic-slice gather stays in range (start + w_cap <= C);
     # widening leftward is always sound.  Growing w_cap loosens the clamp,
     # which can grow a hull, so iterate to the (monotone, bounded) fixpoint.
     while True:
-        if 2 * w_cap > capacity:
+        if not window_fits(w_cap, capacity):
             return None
         for r, (lo, hi) in enumerate(lo_hi):
             lo_c = min(lo, capacity - w_cap)
             los[r] = lo_c
             hulls[r] = hi - lo_c + 1 if hi >= lo_c else 0
             needs[r] = hulls[r] + int(inserts_of[r])
-        new_cap = bucket_length(max(max(needs), 1), minimum=min_width)
+        new_cap = window_cap(max(needs), min_width)
         if new_cap == w_cap:
             break
         w_cap = new_cap

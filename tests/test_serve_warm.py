@@ -17,6 +17,7 @@ from peritext_tpu.ops import TpuUniverse
 from peritext_tpu.runtime import telemetry
 from peritext_tpu.runtime.serve import ROW_BUCKET, cohort_shape_key
 from peritext_tpu.runtime.serve_shard import ShardedServePlane
+from peritext_tpu.testing import window_env
 
 from test_serve import author_stream
 
@@ -162,3 +163,53 @@ def test_only_a_warmed_universe_pads_to_the_warmed_shapes():
     assert warm in uni.warmed_keys
     assert dict(warm[5])["has_marks"] is True and warm[4] == 8
     assert uni._wcaches is None
+
+
+def test_reach_bound_after_a_full_table_flush_is_warm():
+    """A warmed universe whose mirror a full-table flush left stale runs
+    the census's reach bound on its next flushes, rejecting a both-ends
+    edit and letting a caret-local one through to a windowed launch, with
+    no compile outside what ``warm_patched`` compiled and no cold shape."""
+    doc = Doc("w")
+    genesis, _ = doc.change(
+        [
+            {"path": [], "action": "makeList", "key": "text"},
+            {"path": ["text"], "action": "insert", "index": 0, "values": list("reach " * 50)},
+        ]
+    )
+
+    def both_ends():
+        length = sum(len(s["text"]) for s in doc.get_text_with_formatting(["text"]))
+        change, _ = doc.change([
+            {"path": ["text"], "action": "insert", "index": 0, "values": ["<"]},
+            {"path": ["text"], "action": "insert", "index": length + 1, "values": [">"]},
+        ])
+        return change
+
+    with window_env(True, min_cap="512"):
+        uni = TpuUniverse(["a", "b"], capacity=512, max_mark_ops=64)
+        uni.apply_changes({r: [genesis] for r in ("a", "b")})
+        uni.warm_patched()
+        assert ("window_reach", 2, 512) in uni.warmed_keys
+        change = both_ends()
+        uni.apply_changes_with_patches({r: [change] for r in ("a", "b")})
+        assert uni._mirror_token is not uni.states  # full-table: stale
+        compiles = []
+
+        def listen(event, seconds, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(seconds)
+
+        before = dict(uni.stats)
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            for change in (both_ends(), _typed(doc, 150, "x")):
+                uni.apply_changes_with_patches({r: [change] for r in ("a", "b")})
+                assert set(uni.launch_keys) <= uni.warmed_keys
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+    grew = {k: uni.stats.get(k, 0) - before.get(k, 0) for k in (
+        "window_reach_checks", "window_reach_rejects", "window_rebuilds", "windowed_launches")}
+    assert grew == {"window_reach_checks": 2, "window_reach_rejects": 1,
+                    "window_rebuilds": 1, "windowed_launches": 1}, grew
+    assert compiles == []

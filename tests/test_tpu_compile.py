@@ -242,3 +242,25 @@ def test_pallas_kernel_compiles_at_capacity_2048(one_chip, kernel):
         kwargs = {}
     compiled = _compile(fn, args, kwargs, SMALL_R, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_window_reach_bound_stays_fused_at_fleet_shape(one_chip):
+    """The census's reach bound over the benchmark fleet's table (5,120
+    replicas at capacity 2048, ``REACH_IDS`` ids each): the per-id compare
+    over the table stays inside the reduction's fusion, so the program's
+    scratch holds no [R, ids, C] plane, nor even one [R, C] one."""
+    from peritext_tpu.ops import window as W
+
+    replicas, capacity = 5120, 2048
+    args = (
+        np.zeros((SMALL_R, capacity), np.int32),  # elem_ctr
+        np.zeros((SMALL_R, capacity), np.int32),  # elem_act
+        np.zeros(SMALL_R, np.int32),  # length
+        np.zeros((SMALL_R, W.REACH_IDS, 2), np.int32),  # ids
+        np.zeros(SMALL_R, bool),  # head
+        np.zeros(SMALL_R, np.int32),  # inserts
+    )
+    compiled = _compile(K.window_reach_bound, args, {}, replicas, one_chip)
+    _fits_one_chip(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < replicas * capacity * 4
+    assert "merge_step_sorted" not in compiled.as_text()

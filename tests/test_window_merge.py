@@ -268,14 +268,16 @@ def test_over_window_fallback_full_doc_mark():
 
 def test_census_rejection_backoff():
     """A streak of census rejections (persistently table-wide hulls) must
-    trigger the backoff — the census (and its per-batch mirror rebuild) is
-    skipped for a few batches, every skipped batch rides the byte-identical
-    full-table path, and a local edit after the skip window re-engages."""
+    trigger the backoff — the census is skipped for a few batches, every
+    skipped batch rides the byte-identical full-table path, and a local
+    edit after the skip window re-engages.  The stale mirror's rebuild is
+    paid only where the device reach bound cannot rule the window out."""
     d, genesis = _genesis(900)
     batches = [[genesis]]
     # 12 consecutive whole-doc hulls: opposite-end edits.  The first 4
-    # (threshold streak) pay the census + rebuild; the next 8 land inside
-    # the skip window, so their census never runs.
+    # (threshold streak) are rejected by the reach bound, without a
+    # rebuild; the next 8 land inside the skip window, so their census
+    # never runs.
     for i in range(12):
         c, _ = d.change([
             {"path": ["text"], "action": "insert", "index": 1, "values": ["a"]},
@@ -291,8 +293,9 @@ def test_census_rejection_backoff():
     uw, _ = _assert_identical(batches)
     assert uw.stats.get("window_census_skips", 0) == 8, uw.stats
     assert uw.stats.get("windowed_launches", 0) == 1, uw.stats
-    # Only the pre-backoff rejections and the final probe pay a rebuild.
-    assert uw.stats.get("window_rebuilds", 0) == 5, uw.stats
+    assert uw.stats.get("window_reach_rejects", 0) == 4, uw.stats
+    # Only the final probe, which the reach bound lets through, rebuilds.
+    assert uw.stats.get("window_rebuilds", 0) == 1, uw.stats
 
 
 def test_window_engages_only_past_min_capacity():
